@@ -58,7 +58,7 @@ def _bench_round_overhead(emit) -> None:
     weights = jnp.ones(K, jnp.float32)
 
     def legacy():
-        st, m = sfl._jit_round(state, batches, weights)
+        st, m = sfl._jit_round(sfl.base, state, batches, weights)
         jax.block_until_ready(m["loss"])
         return m
 

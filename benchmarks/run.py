@@ -82,13 +82,15 @@ def main() -> None:
         rows.append({"name": name, "us_per_call": round(float(us), 1),
                      "derived": str(derived)})
 
+    failed = []
     for name in picked:
         t0 = time.time()
         try:
             SUITES[name](emit)
             emit(f"{name}/_suite_wall", (time.time() - t0) * 1e6, "ok")
-        except Exception as e:  # noqa: BLE001 — keep the harness running
+        except Exception as e:  # noqa: BLE001 — run the rest, fail at exit
             traceback.print_exc()
+            failed.append(name)
             emit(f"{name}/_suite_wall", (time.time() - t0) * 1e6,
                  f"FAILED:{e!r}")
 
@@ -100,6 +102,8 @@ def main() -> None:
             json.dump({"unix_time": int(time.time()), "rows": picked_rows},
                       f, indent=2)
         print(f"wrote {fname} ({len(picked_rows)} rows)", file=sys.stderr)
+    if failed:
+        raise SystemExit(f"{len(failed)} suite(s) failed: {', '.join(failed)}")
 
 
 if __name__ == "__main__":

@@ -62,6 +62,7 @@ from typing import Any, Dict, Optional, Sequence, Union
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec as P
 
 from ..configs.base import ArchConfig, TrainConfig
 from ..precision import PrecisionConfig, fake_quant, round_key
@@ -70,6 +71,7 @@ from ..models.layers import apply_norm, embed, unembed
 from ..models.model import IGNORE_ID
 from ..models.stack import Runtime, default_train_runtime
 from ..optim import Optimizer, apply_updates
+from ..sharding.specs import CLIENT_AXIS
 from .aggregation import (broadcast_het, fedavg_partial, robust_aggregate,
                           tree_all_finite)
 from .defense import corrupt_updates
@@ -99,13 +101,16 @@ def quantize_activations(s: jax.Array) -> jax.Array:
     return s + jax.lax.stop_gradient(deq - s)
 
 
-def _ce_loss(logits: jax.Array, labels: jax.Array) -> jax.Array:
+def _ce_terms(logits: jax.Array, labels: jax.Array):
+    """Token cross-entropy as (sum over labelled tokens, their count) —
+    the two partial sums a data-parallel server pass reduces across
+    devices before dividing."""
     logits = logits.astype(jnp.float32)
     logz = jax.nn.logsumexp(logits, axis=-1)
     gold = jnp.take_along_axis(logits, jnp.maximum(labels, 0)[..., None],
                                axis=-1)[..., 0]
     mask = (labels != IGNORE_ID).astype(jnp.float32)
-    return jnp.sum((logz - gold) * mask) / jnp.maximum(mask.sum(), 1.0)
+    return jnp.sum((logz - gold) * mask), mask.sum()
 
 
 @jax.tree_util.register_dataclass
@@ -330,21 +335,33 @@ class SflLLM:
                                     jnp.float32)
                            if self.precision.grad_bits < 16 else None)
         self.mesh = mesh              # optional ("clients",) mesh (launch.mesh)
+        if mesh is not None and K % mesh.shape[CLIENT_AXIS]:
+            raise ValueError(f"{K} clients do not split over the "
+                             f"{mesh.shape[CLIENT_AXIS]}-device client mesh")
         self.donate = donate
         # frozen weights, physically partitioned.  Heterogeneous fleets
         # overlap: clients hold the prefix up to max(ell_k), the server
         # holds from min(ell_k) — each sample crosses at its own boundary.
-        self.client_base = {
-            "embed": params["embed"],
-            "layers": jax.tree.map(lambda v: v[:self.rep_max],
-                                   params["layers"]),
+        # Every jitted call takes ``self.base`` as an ARGUMENT: closed over,
+        # the weights would be baked into the executable as constants (a
+        # second device copy of the model and a model-sized compile).
+        base = {
+            "client": {
+                "embed": params["embed"],
+                "layers": jax.tree.map(lambda v: v[:self.rep_max],
+                                       params["layers"]),
+            },
+            "server": {
+                "embed": params["embed"],        # unembedding / LM head
+                "layers": jax.tree.map(lambda v: v[self.rep_min:],
+                                       params["layers"]),
+                "final_norm": params["final_norm"],
+            },
         }
-        self.server_base = {
-            "embed": params["embed"],            # unembedding / LM head
-            "layers": jax.tree.map(lambda v: v[self.rep_min:],
-                                   params["layers"]),
-            "final_norm": params["final_norm"],
-        }
+        if mesh is not None:
+            from ..sharding.specs import replicated_shardings
+            base = jax.device_put(base, replicated_shardings(base, mesh))
+        self.base = base
 
         # ---- hetero bookkeeping: masks, boundaries, adapter scales ------
         # legacy convention keeps the cfg-derived scale; explicit ranks
@@ -371,15 +388,16 @@ class SflLLM:
 
         self._round_traces = 0        # host-side retrace counter (tests)
         self._mask_traces = 0         # ditto for the dropout-mask function
+        # every jitted entry takes the frozen ``base`` first, the state second
         self._jit_local_step = jax.jit(self._local_step)
         self._jit_eval = jax.jit(self._eval_loss)
         # legacy unmasked round — kept as the bench baseline for the
         # masking overhead (benchmarks/bench_dynamic.py); train_round
         # itself always runs the masked graph below
         self._jit_round = jax.jit(self._train_round,
-                                  donate_argnums=(0,) if donate else ())
+                                  donate_argnums=(1,) if donate else ())
         self._jit_round_part = jax.jit(self._train_round_part,
-                                       donate_argnums=(0,) if donate else ())
+                                       donate_argnums=(1,) if donate else ())
         self._jit_mask = jax.jit(self._dropout_mask,
                                  static_argnums=(10, 11, 12))
 
@@ -506,9 +524,10 @@ class SflLLM:
         return jax.device_put(state, sfl_state_shardings(state, self.mesh))
 
     # ------------------------------------------------------------------
-    def _client_forward(self, lora_c, tokens, frontend_emb, rep_hi=None,
-                        lora_scale=None):
+    def _client_forward(self, lora_c, tokens, frontend_emb, cbase,
+                        rep_hi=None, lora_scale=None):
         """One client's FP: embed + layers [0, ell_k) -> activations s_k.
+        ``cbase`` is the frozen client base (``self.base["client"]``).
 
         ``rep_hi`` (heterogeneous splits): the client's own boundary in
         repeat units — the scan runs to max(ell_k) with repeats past the
@@ -517,51 +536,77 @@ class SflLLM:
         cfg, rt = self.cfg, self.rt
         S = tokens.shape[1] + (0 if frontend_emb is None else frontend_emb.shape[1])
         positions = jnp.arange(S, dtype=jnp.int32)
-        x = embed(cfg, self.client_base["embed"], tokens,
-                  positions[-tokens.shape[1]:])
+        x = embed(cfg, cbase["embed"], tokens, positions[-tokens.shape[1]:])
         if frontend_emb is not None:
             x = jnp.concatenate([frontend_emb.astype(x.dtype), x], axis=1)
         x, _, aux = stack_mod.apply_stack(
-            cfg, self.client_base["layers"], x, positions=positions,
+            cfg, cbase["layers"], x, positions=positions,
             lora=lora_c, rt=rt, mode="train",
             rep_gate=(None, rep_hi) if rep_hi is not None else None,
             lora_scale=lora_scale)
         return x, aux
 
-    def _server_loss(self, lora_s, acts, labels, rep_lo=None):
-        """Pooled loss on the main server.  acts: (K, b, S, d).
+    def _server_loss(self, lora_s, acts, labels, sbase, rep_lo=None):
+        """Pooled loss on the main server.  acts: (K, b, S, d); ``sbase``
+        is the frozen server base (``self.base["server"]``).
 
         ``rep_lo`` (heterogeneous splits): per-sample entry depth — repeats
         below each sample's boundary pass through as identity, so every
         client's activation is consumed at its own split depth in one
-        pooled scan."""
+        pooled scan.
+
+        With a client mesh the pooled batch is data-parallel: each device
+        runs the server stack on its own clients' rows inside ``shard_map``
+        (Pallas kernels cannot be partitioned automatically), and the
+        cross-entropy partial sums meet before the divide."""
+        if self.mesh is None:
+            num, den, aux = self._server_terms(lora_s, acts, labels, sbase,
+                                               rep_lo)
+        else:
+            def body(lora_s, acts, labels, sbase, rep_lo):
+                return tuple(t[None] for t in self._server_terms(
+                    lora_s, acts, labels, sbase, rep_lo))
+
+            C = P(CLIENT_AXIS)
+            num, den, aux = jax.shard_map(
+                body, mesh=self.mesh, in_specs=(P(), C, C, P(), C),
+                out_specs=C, check_vma=False)(lora_s, acts, labels, sbase,
+                                              rep_lo)
+            num, den, aux = num.sum(), den.sum(), aux.mean()
+        loss = num / jnp.maximum(den, 1.0)
+        return loss + self.aux_coef * aux, loss
+
+    def _server_terms(self, lora_s, acts, labels, sbase, rep_lo):
+        """Server FP over ``acts``: (CE sum, labelled-token count, aux)."""
         cfg, rt = self.cfg, self.rt
         K, b, S, d = acts.shape
         x = acts.reshape(K * b, S, d)
         positions = jnp.arange(S, dtype=jnp.int32)
         x, _, aux = stack_mod.apply_stack(
-            cfg, self.server_base["layers"], x, positions=positions,
+            cfg, sbase["layers"], x, positions=positions,
             lora=lora_s, rt=rt, mode="train",
             rep_gate=(rep_lo, None) if rep_lo is not None else None,
             lora_scale=self._server_scale)
-        x = apply_norm(cfg, x, self.server_base["final_norm"])
-        logits = unembed(cfg, self.server_base["embed"], x)
+        x = apply_norm(cfg, x, sbase["final_norm"])
+        logits = unembed(cfg, sbase["embed"], x)
         lbl = labels.reshape(K * b, -1)
         F = logits.shape[1] - lbl.shape[1]
         if F > 0:
             logits = logits[:, F:]
-        loss = _ce_loss(logits, lbl)
-        return loss + self.aux_coef * aux, loss
+        num, den = _ce_terms(logits, lbl)
+        return num, den, aux
 
     # ------------------------------------------------------------------
-    def _local_step(self, state: SflState, batches: Dict[str, jax.Array]):
+    def _local_step(self, base, state: SflState,
+                    batches: Dict[str, jax.Array]):
         """One fine-tuning round (steps a-f of Section IV-A).
 
         batches: tokens (K, b, S), labels (K, b, S), optional frontend_emb.
         """
-        return self._step_impl(state, batches, None, None)
+        return self._step_impl(base, state, batches, None, None)
 
-    def _step_impl(self, state: SflState, batches: Dict[str, jax.Array],
+    def _step_impl(self, base, state: SflState,
+                   batches: Dict[str, jax.Array],
                    cfg_dyn: Optional[Dict[str, Any]], part):
         """One local step, optionally under round dynamics.
 
@@ -607,25 +652,38 @@ class SflLLM:
             else:
                 sc = None
 
-            def cf(lora_c, tok, f, rh, s):
-                return self._client_forward(
-                    lora_c, tok, f, rep_hi=rh,
-                    lora_scale=s if s is not None else scales)
-
             in_axes = (0, 0, None if fe is None else 0,
                        0 if het_split else None,
                        0 if sc is not None else None)
-            fwd = lambda ls: jax.vmap(cf, in_axes=in_axes)(
-                ls, tokens, fe, rep_hi, sc)
-        else:
-            def cf(lora_c, tok, f):
-                return self._client_forward(lora_c, tok, f,
-                                            lora_scale=scales)
 
-            if fe is None:
-                fwd = lambda ls: jax.vmap(lambda l, t: cf(l, t, None))(ls, tokens)
-            else:
-                fwd = lambda ls: jax.vmap(cf)(ls, tokens, fe)
+            def fwd_k(ls, tok, f, rh, s, cbase):
+                def cf(lora_c, tok, f, rh, s):
+                    return self._client_forward(
+                        lora_c, tok, f, cbase, rep_hi=rh,
+                        lora_scale=s if s is not None else scales)
+                return jax.vmap(cf, in_axes=in_axes)(ls, tok, f, rh, s)
+
+            client_args = (tokens, fe, rep_hi, sc)
+        else:
+            def fwd_k(ls, tok, f, cbase):
+                def cf(lora_c, tok, f):
+                    return self._client_forward(lora_c, tok, f, cbase,
+                                                lora_scale=scales)
+                if f is None:
+                    return jax.vmap(lambda l, t: cf(l, t, None))(ls, tok)
+                return jax.vmap(cf)(ls, tok, f)
+
+            client_args = (tokens, fe)
+        if self.mesh is not None:
+            # each device runs its own clients' FP (and, through the vjp,
+            # their BP) — an explicit shard_map, because Pallas kernels
+            # cannot be partitioned automatically
+            n_in = len(client_args)
+            fwd_k = jax.shard_map(
+                fwd_k, mesh=self.mesh,
+                in_specs=(P(CLIENT_AXIS),) * (n_in + 1) + (P(),),
+                out_specs=P(CLIENT_AXIS), check_vma=False)
+        fwd = lambda ls: fwd_k(ls, *client_args, base["client"])
         (acts, client_aux), client_vjp = jax.vjp(fwd, state.lora_client)
 
         # boundary quantization (repro.precision): the uploaded payload is
@@ -656,7 +714,8 @@ class SflLLM:
         grad_fn = jax.value_and_grad(self._server_loss, argnums=(0, 1),
                                      has_aux=True)
         (total, loss), (g_server, g_acts) = grad_fn(state.lora_server, acts,
-                                                    labels, rep_lo)
+                                                    labels, base["server"],
+                                                    rep_lo)
 
         # (e) download dL/ds_k; (f) client-side BP --------------------------
         # the downloaded gradient is quantized the same way the uploaded
@@ -760,7 +819,7 @@ class SflLLM:
                                jnp.asarray(list(sample_counts), jnp.float32))
 
     # ------------------------------------------------------------------
-    def _train_round(self, state: SflState, round_batches, weights):
+    def _train_round(self, base, state: SflState, round_batches, weights):
         """One compiled global round: lax.scan over the I local steps, then
         in-graph FedAvg — a single XLA program per round instead of K*I
         host dispatches.
@@ -768,11 +827,12 @@ class SflLLM:
         round_batches: tokens (I, K, b, S), labels (I, K, b, S), optional
         frontend_emb (I, K, b, F, d); weights: (K,) sample counts."""
         self._round_traces += 1       # trace-time only: retrace telemetry
-        state, metrics = jax.lax.scan(self._local_step, state, round_batches)
+        state, metrics = jax.lax.scan(
+            lambda st, b: self._local_step(base, st, b), state, round_batches)
         return self._aggregate(state, weights), metrics
 
-    def _train_round_part(self, state: SflState, round_batches, weights,
-                          part, cfg_dyn, poison=None, robust=None,
+    def _train_round_part(self, base, state: SflState, round_batches,
+                          weights, part, cfg_dyn, poison=None, robust=None,
                           byz=None):
         """The one compiled global round every caller runs: scan + in-graph
         FedAvg with the (K,) participation mask — and optionally a whole
@@ -807,7 +867,7 @@ class SflLLM:
                  else self._client_masks)
         ref = state.lora_client       # pre-round (post-broadcast) adapters
         new, metrics = jax.lax.scan(
-            lambda st, b: self._step_impl(st, b, cfg_dyn, part),
+            lambda st, b: self._step_impl(base, st, b, cfg_dyn, part),
             state, round_batches)
         if byz is not None:
             # corrupted uploads: the radio payload between client and
@@ -922,8 +982,9 @@ class SflLLM:
             part, cfg_dyn = jax.device_put(
                 (part, cfg_dyn),
                 round_dynamics_shardings((part, cfg_dyn), self.mesh))
-        return self._jit_round_part(state, batches, weights, part, cfg_dyn,
-                                    dyn.poison, dyn.robust, dyn.byzantine)
+        return self._jit_round_part(self.base, state, batches, weights, part,
+                                    cfg_dyn, dyn.poison, dyn.robust,
+                                    dyn.byzantine)
 
     def allocation_dynamics(self, ell_k, rank_k,
                             bits_k=None) -> Dict[str, Any]:
@@ -1003,7 +1064,7 @@ class SflLLM:
             state, batches["tokens"].shape[-2:],
             batches.get("frontend_emb"),
             armed_act=self._act_bits is not None)
-        return self._jit_local_step(state, batches)
+        return self._jit_local_step(self.base, state, batches)
 
     def train(self, state: SflState, data_iter, *, global_rounds: int,
               sample_counts, log_every: int = 0, callback=None):
@@ -1026,7 +1087,7 @@ class SflLLM:
         return state, history
 
     # ------------------------------------------------------------------
-    def _eval_loss(self, state: SflState, batch):
+    def _eval_loss(self, base, state: SflState, batch):
         """Validation loss through client 0's adapter (post-aggregation all
         clients share the slots client 0 owns)."""
         lora_c0 = jax.tree.map(lambda v: v[0], state.lora_client)
@@ -1035,17 +1096,19 @@ class SflLLM:
         rep_hi0 = jnp.int32(self.rep_k[0]) if self.hetero_split else None
         acts, _ = self._client_forward(lora_c0, batch["tokens"],
                                        batch.get("frontend_emb"),
-                                       rep_hi=rep_hi0, lora_scale=scale0)
+                                       base["client"], rep_hi=rep_hi0,
+                                       lora_scale=scale0)
         rep_lo = None
         if self.hetero_split:
             b = batch["tokens"].shape[0]
             rep_lo = jnp.full((b,), self.rep_k[0] - self.rep_min, jnp.int32)
-        _, loss = self._server_loss(state.lora_server, acts[None],
-                                    batch["labels"][None], rep_lo)
-        return loss
+        num, den, _ = self._server_terms(state.lora_server, acts[None],
+                                         batch["labels"][None],
+                                         base["server"], rep_lo)
+        return num / jnp.maximum(den, 1.0)
 
     def eval_loss(self, state, batch):
-        return self._jit_eval(state, batch)
+        return self._jit_eval(self.base, state, batch)
 
 
 # ---------------------------------------------------------------------------

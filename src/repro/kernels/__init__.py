@@ -1,5 +1,6 @@
-"""Pallas TPU kernels for the compute hot-spots (validated in interpret
-mode on CPU; set interpret=False on real TPUs):
+"""Pallas TPU kernels for the compute hot-spots.  Each entry dispatches
+itself (``kernels.backend``): native Mosaic kernels on TPU, the jnp oracle
+elsewhere, and Pallas interpret mode when a test passes ``interpret=True``:
 
 * lora_matmul     — fused y = xW + scale·(xAᵀ)Bᵀ (the paper's adapter math)
 * flash_attention — online-softmax causal GQA attention, VMEM-resident tiles

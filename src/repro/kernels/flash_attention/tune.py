@@ -1,27 +1,25 @@
-"""Cache-length block autotuner for the flash-decode kernel, memoized per
-process — the ``lora_matmul/tune.py`` pattern applied to split-K decode.
+"""Cache-length block selection for the flash-decode kernels, memoized per
+process — the ``lora_matmul/tune.py`` rule applied to split-K decode.
 
 ``best_decode_block`` picks the kv-tile size ``bk`` for one
-(B, KH, G, L, D, dtype) decode problem.  On a TPU backend the candidates
-are timed against the real kernel; elsewhere a waste heuristic picks the
-tile: a big bk wastes MXU work on the partially-live last tile of every
-slot (the steady-state live length is unknown at trace time, so the
-heuristic scores the expected half-full tile), a tiny bk pays more grid
-steps and scratch round-trips.  Either way the kernel never launches with
+(B, KH, G, L, D, dtype) decode problem by one deterministic waste rule on
+every backend (the tuners run while the decode step is traced, where
+nothing can be timed): a big bk wastes MXU work on the partially-live
+last tile of every slot (the steady-state live length is unknown at trace
+time, so the rule scores the expected half-full tile), a tiny bk pays
+more grid steps and scratch round-trips.  The kernel never launches with
 a pathological tile — a bk past the VMEM budget or wider than the cache.
 """
 from __future__ import annotations
 
-import time
-from typing import Dict, List, Tuple
+from typing import Dict, Tuple
 
-import jax
 import jax.numpy as jnp
 
-# key: (B, KH, G, L, D, q dtype, KV dtype, backend) — the kv dtype keys
-# the int8-KV variant separately: its tiles cost a quarter of the f32
-# VMEM, so the winning bk differs from the same logical shape in f32
-_CACHE: Dict[Tuple[int, int, int, int, int, str, str, str], int] = {}
+# key: (B, KH, G, L, D, q dtype, KV dtype) — the kv dtype keys the int8-KV
+# variant separately: its tiles cost a quarter of the f32 VMEM, so the
+# winning bk differs from the same logical shape in f32
+_CACHE: Dict[Tuple[int, int, int, int, int, str, str], int] = {}
 
 _CANDIDATES: Tuple[int, ...] = (128, 256, 512, 1024)
 _VMEM_BUDGET = 12 * 1024 * 1024        # leave headroom under ~16 MB/core
@@ -40,43 +38,6 @@ def _vmem_bytes(bk: int, G: int, D: int, itemsize: int,
     return 2 * tiles + scratch + itemsize * G * D
 
 
-def _time_candidates(B: int, KH: int, G: int, L: int, D: int, dtype,
-                     cands: List[int], kv_dtype=None) -> int:
-    from .decode import flash_decode_kernel, flash_decode_q8_kernel
-
-    int8_kv = kv_dtype is not None and jnp.dtype(kv_dtype) == jnp.int8
-    q = jnp.zeros((B, KH, G, D), dtype)
-    lens = jnp.full((B,), L, jnp.int32)
-    scale = jnp.ones((KH,), jnp.float32)
-    best, best_t = cands[0], float("inf")
-    for bk in cands:
-        # time against the padded cache length ops.flash_decode will run
-        Lp = -(-L // bk) * bk
-        try:
-            if int8_kv:
-                k = jnp.zeros((B, KH, Lp, D), jnp.int8)
-                fn = jax.jit(lambda q, k, v, n, s, bk=bk:
-                             flash_decode_q8_kernel(q, k, v, n, s, s, bk=bk,
-                                                    interpret=False))
-                args = (q, k, k, lens, scale)
-            else:
-                k = jnp.zeros((B, KH, Lp, D), dtype)
-                fn = jax.jit(lambda q, k, v, n, bk=bk: flash_decode_kernel(
-                    q, k, v, n, bk=bk, interpret=False))
-                args = (q, k, k, lens)
-            fn(*args).block_until_ready()                   # compile
-            t = float("inf")
-            for _ in range(3):
-                t0 = time.perf_counter()
-                fn(*args).block_until_ready()
-                t = min(t, time.perf_counter() - t0)
-        except Exception:                                   # noqa: BLE001
-            continue            # tile shape the backend rejects — skip it
-        if t < best_t:
-            best, best_t = bk, t
-    return best
-
-
 def _heuristic_key(L: int, bk: int):
     """Expected wasted lanes on the half-full boundary tile, then fewer
     grid steps (scratch round-trips) as the tie-break."""
@@ -85,16 +46,14 @@ def _heuristic_key(L: int, bk: int):
 
 
 def best_decode_block(B: int, KH: int, G: int, L: int, D: int,
-                      dtype=jnp.float32, backend: str | None = None,
-                      kv_dtype=None) -> int:
+                      dtype=jnp.float32, kv_dtype=None) -> int:
     """Memoized ``bk`` for one flash-decode problem shape.
 
     ``kv_dtype`` (default: same as ``dtype``) keys the int8-KV variant
     separately — smaller kv tiles admit larger candidates."""
-    backend = backend or jax.default_backend()
     kv_name = jnp.dtype(kv_dtype if kv_dtype is not None else dtype).name
     key = (int(B), int(KH), int(G), int(L), int(D),
-           jnp.dtype(dtype).name, kv_name, backend)
+           jnp.dtype(dtype).name, kv_name)
     hit = _CACHE.get(key)
     if hit is not None:
         return hit
@@ -104,11 +63,7 @@ def best_decode_block(B: int, KH: int, G: int, L: int, D: int,
              if _vmem_bytes(min(bk, L), max(G, 1), D, itemsize,
                             kv_itemsize=kv_itemsize) <= _VMEM_BUDGET]
     cands = sorted(set(cands)) or [min(128, L)]
-    if backend == "tpu":
-        best = _time_candidates(B, KH, G, L, D, dtype, cands,
-                                kv_dtype=kv_dtype)
-    else:
-        best = min(cands, key=lambda bk: _heuristic_key(L, bk))
+    best = min(cands, key=lambda bk: _heuristic_key(L, bk))
     _CACHE[key] = best
     return best
 
@@ -116,64 +71,24 @@ def best_decode_block(B: int, KH: int, G: int, L: int, D: int,
 # -- paged decode: the kv tile must divide the page size --------------------
 
 # key additionally carries the KV-pool dtype (int8 pools key separately)
-_PAGED_CACHE: Dict[Tuple[int, int, int, int, int, int, str, str, str],
-                   int] = {}
+_PAGED_CACHE: Dict[Tuple[int, int, int, int, int, int, str, str], int] = {}
 
 
 def clear_paged_cache() -> None:
     _PAGED_CACHE.clear()
 
 
-def _time_paged_candidates(B: int, KH: int, G: int, MP: int, PS: int, D: int,
-                           dtype, cands: List[int], kv_dtype=None) -> int:
-    from .paged_decode import paged_decode_kernel, paged_decode_q8_kernel
-
-    int8_kv = kv_dtype is not None and jnp.dtype(kv_dtype) == jnp.int8
-    NP = B * MP + 1                                  # pool incl. null page
-    q = jnp.zeros((B, KH, G, D), dtype)
-    kp = jnp.zeros((KH, NP, PS, D), jnp.int8 if int8_kv else dtype)
-    bt = (jnp.arange(B * MP, dtype=jnp.int32).reshape(B, MP) + 1)
-    lens = jnp.full((B,), MP * PS, jnp.int32)
-    scale = jnp.ones((KH,), jnp.float32)
-    best, best_t = cands[0], float("inf")
-    for bk in cands:
-        try:
-            if int8_kv:
-                fn = jax.jit(lambda q, k, v, n, t, s, bk=bk:
-                             paged_decode_q8_kernel(q, k, v, n, t, s, s,
-                                                    bk=bk, interpret=False))
-                args = (q, kp, kp, lens, bt, scale)
-            else:
-                fn = jax.jit(lambda q, k, v, n, t, bk=bk: paged_decode_kernel(
-                    q, k, v, n, t, bk=bk, interpret=False))
-                args = (q, kp, kp, lens, bt)
-            fn(*args).block_until_ready()                     # compile
-            t = float("inf")
-            for _ in range(3):
-                t0 = time.perf_counter()
-                fn(*args).block_until_ready()
-                t = min(t, time.perf_counter() - t0)
-        except Exception:                                     # noqa: BLE001
-            continue            # tile shape the backend rejects — skip it
-        if t < best_t:
-            best, best_t = bk, t
-    return best
-
-
 def best_paged_block(B: int, KH: int, G: int, MP: int, PS: int, D: int,
-                     dtype=jnp.float32, backend: str | None = None,
-                     kv_dtype=None) -> int:
+                     dtype=jnp.float32, kv_dtype=None) -> int:
     """Memoized kv-tile size for one paged-decode problem — the
     ``(page_size, bk)`` twin of ``best_decode_block``.  Candidates are the
     divisors of ``page_size`` within the VMEM budget (a paged tile can
-    never span two pages: they are not adjacent in the pool), timed
-    against the real kernel on TPU; elsewhere the largest divisor wins —
-    paged tiles are fully live up to the length boundary, so fewer grid
-    steps is the whole game."""
-    backend = backend or jax.default_backend()
+    never span two pages: they are not adjacent in the pool), and the
+    largest wins — paged tiles are fully live up to the length boundary,
+    so fewer grid steps is the whole game."""
     kv_name = jnp.dtype(kv_dtype if kv_dtype is not None else dtype).name
     key = (int(B), int(KH), int(G), int(MP), int(PS), int(D),
-           jnp.dtype(dtype).name, kv_name, backend)
+           jnp.dtype(dtype).name, kv_name)
     hit = _PAGED_CACHE.get(key)
     if hit is not None:
         return hit
@@ -184,10 +99,6 @@ def best_paged_block(B: int, KH: int, G: int, MP: int, PS: int, D: int,
              and _vmem_bytes(bk, max(G, 1), D, itemsize,
                              kv_itemsize=kv_itemsize) <= _VMEM_BUDGET]
     cands = sorted(cands) or [PS]
-    if backend == "tpu":
-        best = _time_paged_candidates(B, KH, G, MP, PS, D, dtype, cands,
-                                      kv_dtype=kv_dtype)
-    else:
-        best = cands[-1]
+    best = cands[-1]
     _PAGED_CACHE[key] = best
     return best

@@ -218,7 +218,7 @@ def _gather_kernel(idx_ref, x_ref, w_ref, a_ref, b_ref, y_ref, acc_ref,
         acc_ref[...] = jnp.zeros_like(acc_ref)
         z_ref[...] = jnp.zeros_like(z_ref)
 
-    xb = x_ref[...]                                       # (1, bk)
+    xb = x_ref[0]                                         # (1, bk)
     acc_ref[...] += jnp.dot(xb, w_ref[...],
                             preferred_element_type=jnp.float32)
     # this row's OWN adapter tile: the prefetched index map already DMA'd
@@ -230,7 +230,7 @@ def _gather_kernel(idx_ref, x_ref, w_ref, a_ref, b_ref, y_ref, acc_ref,
     def _finish():
         y = acc_ref[...] + scale * jnp.dot(
             z_ref[...], b_ref[0].T, preferred_element_type=jnp.float32)
-        y_ref[...] = y.astype(y_ref.dtype)
+        y_ref[0] = y.astype(y_ref.dtype)
 
 
 def lora_matmul_gather_kernel(x, w, a_pool, b_pool, idx, *, scale: float,
@@ -253,7 +253,9 @@ def lora_matmul_gather_kernel(x, w, a_pool, b_pool, idx, *, scale: float,
     Grid (M, N/bn, K/bk): one grid row per slot (decode batches are
     slot-count sized, so bm == 1 costs nothing and lets neighbouring rows
     wear different adapters).  N and K must divide by the block shape
-    (ops.py pads).
+    (ops.py pads).  x and y are viewed as (M, 1, K) / (M, 1, N) so each
+    row's (1, 1, bk) block spans the full second-minor dimension — Mosaic
+    refuses a (1, bk) block of an (M, K) array unless M == 1.
     """
     M, K = x.shape
     N = w.shape[1]
@@ -265,23 +267,24 @@ def lora_matmul_gather_kernel(x, w, a_pool, b_pool, idx, *, scale: float,
         num_scalar_prefetch=1,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, bk), lambda m, j, k, idx: (m, k)),         # x
+            pl.BlockSpec((1, 1, bk), lambda m, j, k, idx: (m, 0, k)),   # x
             pl.BlockSpec((bk, bn), lambda m, j, k, idx: (k, j)),        # w
             pl.BlockSpec((1, r, bk),
                          lambda m, j, k, idx: (idx[m], 0, k)),          # A
             pl.BlockSpec((1, bn, r),
                          lambda m, j, k, idx: (idx[m], j, 0)),          # B
         ],
-        out_specs=pl.BlockSpec((1, bn), lambda m, j, k, idx: (m, j)),
+        out_specs=pl.BlockSpec((1, 1, bn), lambda m, j, k, idx: (m, 0, j)),
         scratch_shapes=[pltpu.VMEM((1, bn), jnp.float32),
                         pltpu.VMEM((1, r), jnp.float32)],
     )
-    return pl.pallas_call(
+    y = pl.pallas_call(
         functools.partial(_gather_kernel, scale=scale, k_steps=grid[2]),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
+        out_shape=jax.ShapeDtypeStruct((M, 1, N), x.dtype),
         interpret=interpret,
-    )(idx.astype(jnp.int32), x, w, a_pool, b_pool)
+    )(idx.astype(jnp.int32), x.reshape(M, 1, K), w, a_pool, b_pool)
+    return y.reshape(M, N)
 
 
 # ---------------------------------------------------------------------------

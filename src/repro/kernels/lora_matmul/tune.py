@@ -1,37 +1,35 @@
-"""Block-size autotuner for the fused LoRA kernels, memoized per process.
+"""Block-size selection for the fused LoRA kernels, memoized per process.
 
-``best_blocks`` sweeps (bm, bn, bk) candidates for one (M, K, N, r, dtype)
-problem shape and caches the winner, so every (projection shape x dtype)
-pair in a model pays the sweep at most once per process.  On a TPU backend
-the candidates are timed against the real kernel; elsewhere (CPU dry runs,
-interpret mode) timing a Python-interpreted kernel is meaningless, so a
-padding-waste heuristic picks the tiles.  Either way the point is the
-same: the kernel is never launched with pathological tiles — a bk that
-blows the VMEM budget, or 256-wide blocks wrapped around a 33-row ragged
-matmul that would waste 7/8 of every MXU pass on padding.
+``best_blocks`` picks (bm, bn, bk) for one (M, K, N, r, dtype) problem
+shape by one deterministic rule on every backend: drop the candidates
+past the VMEM budget, then minimize padding waste.  The tiles a CPU
+compile rehearsal lowers are therefore exactly the tiles the chip runs,
+and the choice is made from shapes alone — the tuners are called while
+the model's jitted step is being traced, where nothing can be timed.
+The kernel is never launched with pathological tiles: a bk that blows
+the VMEM budget, or 256-wide blocks wrapped around a 33-row ragged matmul
+that would waste 7/8 of every MXU pass on padding.
 """
 from __future__ import annotations
 
-import time
 from typing import Dict, List, Tuple
 
-import jax
 import jax.numpy as jnp
 
 Blocks = Tuple[int, int, int]
 GatherBlocks = Tuple[int, int]
 
-# key: (M, K, N, r, x dtype, WEIGHT dtype, backend) — the weight dtype is
-# part of the key because the int8 base variant has its own VMEM footprint
-# and its own winner: an (int8 W, f32 scale) sweep must never alias the
+# key: (M, K, N, r, x dtype, WEIGHT dtype) — the weight dtype is part of
+# the key because the int8 base variant has its own VMEM footprint and its
+# own winner: an (int8 W, f32 scale) choice must never alias the
 # f32-weight entry for the same logical shape
-_CACHE: Dict[Tuple[int, int, int, int, str, str, str], Blocks] = {}
+_CACHE: Dict[Tuple[int, int, int, int, str, str], Blocks] = {}
 # the gathered (multi-tenant) variant memoizes SEPARATELY, and its key
 # additionally covers the adapter-pool size and the index dtype: a
 # single-adapter sweep and a multi-tenant sweep over the same (M, K, N, r)
 # must never collide — the gather kernel's tiling trade-offs (bm == 1,
 # per-row A/B DMA) are different from the dense kernel's
-_GATHER_CACHE: Dict[Tuple[int, int, int, int, int, str, str, str],
+_GATHER_CACHE: Dict[Tuple[int, int, int, int, int, str, str],
                     GatherBlocks] = {}
 
 _CANDIDATES: Tuple[Blocks, ...] = (
@@ -72,58 +70,15 @@ def _heuristic_key(M: int, K: int, N: int, c: Blocks):
     return (padded, _pad_up(K, bk) // bk, -(bm * bn))
 
 
-def _time_candidates(M: int, K: int, N: int, r: int, dtype,
-                     cands: List[Blocks], w_dtype=None) -> Blocks:
-    """Time the real kernel per candidate (TPU path); min-of-3 wall time."""
-    from .kernel import lora_matmul_kernel, lora_matmul_q8_kernel
-
-    int8_w = w_dtype is not None and jnp.dtype(w_dtype) == jnp.int8
-    best, best_t = cands[0], float("inf")
-    for bm, bn, bk in cands:
-        Mp, Kp, Np = _pad_up(M, bm), _pad_up(K, bk), _pad_up(N, bn)
-        x = jnp.zeros((Mp, Kp), dtype)
-        a = jnp.zeros((r, Kp), dtype)
-        b = jnp.zeros((Np, r), dtype)
-        try:
-            if int8_w:
-                w = jnp.zeros((Kp, Np), jnp.int8)
-                ws = jnp.ones((1, Np), jnp.float32)
-                fn = jax.jit(lambda x, w, ws, a, b, bm=bm, bn=bn, bk=bk:
-                             lora_matmul_q8_kernel(x, w, ws, a, b, scale=1.0,
-                                                   bm=bm, bn=bn, bk=bk,
-                                                   interpret=False))
-                args = (x, w, ws, a, b)
-            else:
-                w = jnp.zeros((Kp, Np), dtype)
-                fn = jax.jit(lambda x, w, a, b, bm=bm, bn=bn, bk=bk:
-                             lora_matmul_kernel(x, w, a, b, scale=1.0, bm=bm,
-                                                bn=bn, bk=bk,
-                                                interpret=False))
-                args = (x, w, a, b)
-            fn(*args).block_until_ready()               # compile
-            t = float("inf")
-            for _ in range(3):
-                t0 = time.perf_counter()
-                fn(*args).block_until_ready()
-                t = min(t, time.perf_counter() - t0)
-        except Exception:                               # noqa: BLE001
-            continue            # tile shape the backend rejects — skip it
-        if t < best_t:
-            best, best_t = (bm, bn, bk), t
-    return best
-
-
 def best_blocks(M: int, K: int, N: int, r: int, dtype=jnp.float32,
-                backend: str | None = None, w_dtype=None) -> Blocks:
+                w_dtype=None) -> Blocks:
     """Memoized (bm, bn, bk) for one fused-LoRA problem shape.
 
     ``w_dtype`` (default: same as ``dtype``) keys the weight-only
     quantized variant separately — an int8 base halves the W tile's VMEM
     and shifts the tiling optimum."""
-    backend = backend or jax.default_backend()
     w_name = jnp.dtype(w_dtype if w_dtype is not None else dtype).name
-    key = (int(M), int(K), int(N), int(r), jnp.dtype(dtype).name, w_name,
-           backend)
+    key = (int(M), int(K), int(N), int(r), jnp.dtype(dtype).name, w_name)
     hit = _CACHE.get(key)
     if hit is not None:
         return hit
@@ -139,10 +94,7 @@ def best_blocks(M: int, K: int, N: int, r: int, dtype=jnp.float32,
             cands.append(c)
     if not cands:
         cands = [(min(128, M), min(128, N), min(128, K))]
-    if backend == "tpu":
-        best = _time_candidates(M, K, N, r, dtype, cands, w_dtype=w_dtype)
-    else:
-        best = min(cands, key=lambda c: _heuristic_key(M, K, N, c))
+    best = min(cands, key=lambda c: _heuristic_key(M, K, N, c))
     _CACHE[key] = best
     return best
 
@@ -168,45 +120,12 @@ def _gather_heuristic_key(K: int, N: int, c: GatherBlocks):
     return (padded, _pad_up(K, bk) // bk, -bn)
 
 
-def _time_gather_candidates(M: int, K: int, N: int, r: int, pool: int,
-                            dtype, idx_dtype,
-                            cands: List[GatherBlocks]) -> GatherBlocks:
-    """Time the real gather kernel per candidate (TPU path)."""
-    from .kernel import lora_matmul_gather_kernel
-
-    best, best_t = cands[0], float("inf")
-    for bn, bk in cands:
-        Kp, Np = _pad_up(K, bk), _pad_up(N, bn)
-        x = jnp.zeros((M, Kp), dtype)
-        w = jnp.zeros((Kp, Np), dtype)
-        a = jnp.zeros((pool, r, Kp), dtype)
-        b = jnp.zeros((pool, Np, r), dtype)
-        idx = jnp.zeros((M,), idx_dtype)
-        try:
-            fn = jax.jit(lambda x, w, a, b, idx, bn=bn, bk=bk:
-                         lora_matmul_gather_kernel(x, w, a, b, idx, scale=1.0,
-                                                   bn=bn, bk=bk,
-                                                   interpret=False))
-            fn(x, w, a, b, idx).block_until_ready()     # compile
-            t = float("inf")
-            for _ in range(3):
-                t0 = time.perf_counter()
-                fn(x, w, a, b, idx).block_until_ready()
-                t = min(t, time.perf_counter() - t0)
-        except Exception:                               # noqa: BLE001
-            continue            # tile shape the backend rejects — skip it
-        if t < best_t:
-            best, best_t = (bn, bk), t
-    return best
-
-
 def best_gather_blocks(M: int, K: int, N: int, r: int, pool: int,
-                       dtype=jnp.float32, idx_dtype=jnp.int32,
-                       backend: str | None = None) -> GatherBlocks:
+                       dtype=jnp.float32,
+                       idx_dtype=jnp.int32) -> GatherBlocks:
     """Memoized (bn, bk) for one batched-gather LoRA problem shape."""
-    backend = backend or jax.default_backend()
     key = (int(M), int(K), int(N), int(r), int(pool),
-           jnp.dtype(dtype).name, jnp.dtype(idx_dtype).name, backend)
+           jnp.dtype(dtype).name, jnp.dtype(idx_dtype).name)
     hit = _GATHER_CACHE.get(key)
     if hit is not None:
         return hit
@@ -221,10 +140,6 @@ def best_gather_blocks(M: int, K: int, N: int, r: int, pool: int,
             cands.append(c)
     if not cands:
         cands = [(min(128, N), min(128, K))]
-    if backend == "tpu":
-        best = _time_gather_candidates(M, K, N, r, pool, dtype, idx_dtype,
-                                       cands)
-    else:
-        best = min(cands, key=lambda c: _gather_heuristic_key(K, N, c))
+    best = min(cands, key=lambda c: _gather_heuristic_key(K, N, c))
     _GATHER_CACHE[key] = best
     return best

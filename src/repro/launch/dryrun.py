@@ -24,7 +24,7 @@ from ..models.stack import Runtime
 from ..optim import adamw
 from ..sharding import (batch_shardings, cache_shardings, lora_shardings,
                         opt_state_shardings, params_shardings)
-from .mesh import make_production_mesh, use_mesh
+from .mesh import make_production_mesh
 from .steps import (arch_for_shape, input_specs, make_decode_step,
                     make_prefill_step, make_train_step)
 
@@ -104,7 +104,7 @@ def dryrun_one(arch_name: str, shape_name: str, *, multi_pod: bool = False,
         full_finetune=full_finetune)
 
     t0 = time.time()
-    with use_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         lowered = jax.jit(step, in_shardings=shardings).lower(*args)
         t1 = time.time()
         compiled = lowered.compile()

@@ -1,49 +1,35 @@
 """Production mesh builders (TPU v5e pods; host-device placeholders on CPU).
 
 FUNCTIONS, not module-level constants — importing this module must not
-touch jax device state.
-
-Also the home of two jax-version compat shims: ``AxisType``/``set_mesh``
-only exist on newer jax, so mesh construction and "enter this mesh" go
-through :func:`make_mesh_compat` / :func:`use_mesh` everywhere.
+touch jax device state.  Every mesh here uses ``AxisType.Auto`` axes (the
+GSPMD-propagated sharding the partition rules in ``repro.sharding`` are
+written for); ``jax.make_mesh`` alone defaults to explicit axes.  Make a
+mesh ambient with ``jax.sharding.set_mesh(mesh)``.
 """
 from __future__ import annotations
 
 from typing import Optional, Sequence, Tuple
 
 import jax
+from jax.sharding import AxisType
 
 
-def make_mesh_compat(shape: Tuple[int, ...], axes: Tuple[str, ...], *,
-                     devices: Optional[Sequence] = None):
-    """jax.make_mesh with AxisType.Auto when the installed jax has axis
-    types, plain make_mesh otherwise."""
-    try:
-        from jax.sharding import AxisType
-        return jax.make_mesh(shape, axes, devices=devices,
-                             axis_types=(AxisType.Auto,) * len(axes))
-    except (ImportError, TypeError):
-        return jax.make_mesh(shape, axes, devices=devices)
-
-
-def use_mesh(mesh):
-    """Context manager making ``mesh`` ambient: jax.sharding.set_mesh on
-    new jax, the Mesh context manager on old jax."""
-    set_mesh = getattr(jax.sharding, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    return mesh
+def make_auto_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...], *,
+                   devices: Optional[Sequence] = None):
+    """``jax.make_mesh`` with every axis ``AxisType.Auto``."""
+    return jax.make_mesh(shape, axes, devices=devices,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return make_auto_mesh(shape, axes)
 
 
 def make_debug_mesh(data: int = 2, model: int = 2):
     """Small mesh for CPU tests (requires >= data*model host devices)."""
-    return make_mesh_compat((data, model), ("data", "model"))
+    return make_auto_mesh((data, model), ("data", "model"))
 
 
 def make_client_mesh(num_devices: Optional[int] = None):
@@ -52,4 +38,4 @@ def make_client_mesh(num_devices: Optional[int] = None):
     multiple of the device count).  Defaults to every visible device."""
     devs = jax.devices()
     n = num_devices or len(devs)
-    return make_mesh_compat((n,), ("clients",), devices=devs[:n])
+    return make_auto_mesh((n,), ("clients",), devices=devs[:n])

@@ -66,6 +66,9 @@ def main() -> None:
     from ..models import init_lora_stack, init_params
     from ..models.generate import SampleConfig
     from ..serving import AdapterRegistry, Request, ServingEngine
+    from .compile_cache import enable_compile_cache
+
+    print(f"compile cache: {enable_compile_cache()}")
 
     cfg = get_arch(args.arch)
     if args.reduced:

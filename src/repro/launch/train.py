@@ -53,8 +53,11 @@ def main() -> None:
     from ..data import WordTokenizer, e2e_splits, iid_partition, sfl_batches
     from ..models import init_lora_stack, init_params
     from ..optim import adamw
+    from .compile_cache import enable_compile_cache
     from .engine import PodRound, SflRound, Trainer
-    from .mesh import make_client_mesh, make_mesh_compat
+    from .mesh import make_auto_mesh, make_client_mesh
+
+    print(f"compile cache: {enable_compile_cache()}")
 
     cfg = get_arch(args.arch)
     if args.reduced:
@@ -89,12 +92,19 @@ def main() -> None:
           f"modeled total delay {hist[-1]:.1f}s (using split={ell_c})")
 
     if args.mode == "sfl":
-        # client-axis data parallelism when the device count divides K
-        n_dev = len(jax.devices())
-        mesh = (make_client_mesh() if n_dev > 1
-                and args.clients % n_dev == 0 else None)
-        if mesh is not None:
-            print(f"sharding the client axis over {n_dev} devices")
+        # client-axis data parallelism over every visible device; K must
+        # split evenly, or the round would silently run on one device
+        devs = jax.devices()
+        n_dev = len(devs)
+        if n_dev > 1 and args.clients % n_dev:
+            raise SystemExit(
+                f"--clients {args.clients} is not a multiple of the "
+                f"{n_dev} visible devices; pass a multiple of {n_dev}")
+        mesh = make_client_mesh() if n_dev > 1 else None
+        print(f"round runs on {n_dev} {devs[0].platform} device(s) "
+              f"({devs[0].device_kind})"
+              + (f", client axis sharded {args.clients // n_dev} per device"
+                 if mesh is not None else ""))
         sfl = SflLLM(cfg, params, ell_c=ell_c, train_cfg=tc,
                      optimizer=adamw(args.lr), mesh=mesh)
         state = sfl.init_state(lora)
@@ -105,7 +115,7 @@ def main() -> None:
         algo = SflRound(sfl, [len(p) for p in parts])
     else:
         n = len(jax.devices())
-        mesh = make_mesh_compat((n, 1), ("data", "model"))
+        mesh = make_auto_mesh((n, 1), ("data", "model"))
         algo = PodRound(cfg, params, None,      # None -> fast train defaults
                         adamw(args.lr), mesh)
         state = algo.init_state(lora)

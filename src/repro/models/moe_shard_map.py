@@ -26,7 +26,6 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -106,11 +105,11 @@ def apply_moe_shard_map(cfg, p: dict, x: jax.Array, mesh: Mesh, *,
 
     body = functools.partial(_local_moe, cfg, tp_size=tp_size,
                              capacity=capacity, tp_axis=tp_axis)
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(dp_axes, tp_axis, None), P(None, None),
                   P(tp_axis, None, None), P(tp_axis, None, None),
                   P(tp_axis, None, None)),
         out_specs=P(dp_axes, tp_axis, None),
-        check_rep=False)
+        check_vma=False)
     return fn(x, p["router"]["w"], p["w_gate"], p["w_up"], p["w_down"])

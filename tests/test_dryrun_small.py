@@ -19,7 +19,7 @@ SCRIPT = textwrap.dedent("""
     import json, sys
     import jax, jax.numpy as jnp
     from repro.configs import get_arch, get_shape
-    from repro.launch.mesh import make_mesh_compat, use_mesh
+    from repro.launch.mesh import make_auto_mesh
     from repro.launch.steps import (arch_for_shape, input_specs,
                                     make_decode_step, make_prefill_step,
                                     make_train_step)
@@ -31,7 +31,7 @@ SCRIPT = textwrap.dedent("""
     from repro.analysis.roofline import build_report
 
     arch, shape_name = sys.argv[1], sys.argv[2]
-    mesh = make_mesh_compat((4, 2), ("data", "model"))
+    mesh = make_auto_mesh((4, 2), ("data", "model"))
     shape = get_shape(shape_name)
     cfg = arch_for_shape(get_arch(arch), shape).reduced(
         num_layers=None or max(2, len(get_arch(arch).pattern)), d_model=256)
@@ -58,7 +58,7 @@ SCRIPT = textwrap.dedent("""
         sh = (params_shardings(args[0], mesh), lora_shardings(args[1], mesh),
               batch_shardings(args[2], mesh), cache_shardings(args[3], mesh),
               batch_shardings(args[4], mesh))
-    with use_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         compiled = jax.jit(step, in_shardings=sh).lower(*args).compile()
     rep = build_report(arch=arch, shape_cfg=shape, mesh_name="4x2", chips=8,
                        compiled=compiled, lowered_text=None, cfg=cfg)

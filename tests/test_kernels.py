@@ -105,6 +105,37 @@ def test_lora_block_autotuner_memoizes_and_clips():
     assert bm * bn * bk <= 128 ** 3
 
 
+def _tuner_calls():
+    from repro.kernels.flash_attention import tune as ft
+    from repro.kernels.lora_matmul import tune as lt
+    return {
+        "lora": (lt.clear_cache,
+                 lambda: lt.best_blocks(2048, 768, 3072, 4)),
+        "lora_gather": (lt.clear_cache,
+                        lambda: lt.best_gather_blocks(4, 768, 3072, 4, 4)),
+        "flash_decode": (ft.clear_cache,
+                         lambda: ft.best_decode_block(4, 12, 1, 128, 64)),
+        "paged_decode": (ft.clear_paged_cache,
+                         lambda: ft.best_paged_block(4, 12, 1, 8, 16, 64)),
+    }
+
+
+@pytest.mark.parametrize("tuner", ["lora", "lora_gather", "flash_decode",
+                                   "paged_decode"])
+def test_tuners_pick_the_same_tiles_on_every_backend(monkeypatch, tuner):
+    """One deterministic rule on every backend: a CPU compile rehearsal
+    lowers exactly the tiles the chip runs (the tuners are called while
+    the step is traced, where no candidate can be timed)."""
+    clear, pick = _tuner_calls()[tuner]
+    picks = {}
+    for name in ("cpu", "tpu", "gpu"):
+        monkeypatch.setattr(jax, "default_backend", lambda n=name: n)
+        clear()
+        picks[name] = pick()
+    clear()
+    assert picks["cpu"] == picks["tpu"] == picks["gpu"], picks
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("B,Sq,Sk,H,KH,D,win",
                          [(2, 64, 64, 4, 2, 32, 0),

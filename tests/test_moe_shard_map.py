@@ -15,13 +15,13 @@ SCRIPT = textwrap.dedent("""
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.configs import get_arch
-    from repro.launch.mesh import make_mesh_compat, use_mesh
+    from repro.launch.mesh import make_auto_mesh
     from repro.models.moe import apply_moe, init_moe
     from repro.models.moe_shard_map import apply_moe_shard_map
 
     cfg = get_arch("olmoe-1b-7b").reduced(d_model=64)   # E=4, top-2
     cfg = cfg.replace(num_experts=4, experts_per_token=2, d_ff=32)
-    mesh = make_mesh_compat((2, 4), ("data", "model"))
+    mesh = make_auto_mesh((2, 4), ("data", "model"))
     key = jax.random.key(0)
     p = init_moe(cfg, key, jnp.float32)
     B, S, d = 4, 16, cfg.d_model
@@ -30,7 +30,7 @@ SCRIPT = textwrap.dedent("""
     # reference: einsum path with no dropping (single token groups)
     y_ref, _ = apply_moe(cfg, p, x, group_size=1, capacity_factor=4.0)
 
-    with use_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         xs = jax.device_put(x, NamedSharding(mesh, P(("data",), "model", None)))
         ps = jax.tree.map(lambda v: jax.device_put(v, NamedSharding(
             mesh, P(*( ("model",) + (None,)*(v.ndim-1) if v.ndim == 3

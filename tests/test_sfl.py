@@ -103,3 +103,39 @@ def test_eval_loss_finite(key):
     state = sfl.init_state(lora)
     val = {"tokens": batches["tokens"][0], "labels": batches["labels"][0]}
     assert np.isfinite(float(sfl.eval_loss(state, val)))
+
+
+def _constant_bytes(stablehlo_text: str) -> int:
+    """Bytes held by the constants of a lowered StableHLO module."""
+    import re
+
+    itemsize = {"f32": 4, "i32": 4, "ui32": 4, "bf16": 2, "f16": 2,
+                "i8": 1, "i1": 1}
+    total = 0
+    for t in re.findall(r"stablehlo\.constant dense(?:_resource)?<.*?>\s*"
+                        r":\s*tensor<([^>]*)>", stablehlo_text):
+        *dims, dt = t.split("x")
+        total += int(np.prod([int(d) for d in dims])) * itemsize.get(dt, 8)
+    return total
+
+
+@pytest.mark.parametrize("ell_c", [2, (1, 2, 3)])
+def test_round_takes_frozen_weights_as_arguments(key, ell_c):
+    """The compiled round receives the frozen base as arguments: closed
+    over, the weights would be baked into the executable as constants (a
+    second device copy of the model and a model-sized compile)."""
+    K, b, S, I = 3, 2, 16, 2
+    cfg, params, lora, _ = _setup(key, K=K, b=b, S=S)
+    tc = TrainConfig(num_clients=K, batch_size=b, local_steps=I)
+    sfl = SflLLM(cfg, params, ell_c=ell_c, train_cfg=tc,
+                 optimizer=adamw(1e-3), donate=False)
+    state = sfl.init_state(lora)
+    tok = jnp.zeros((I, K, b, S), jnp.int32)
+    lowered = sfl._jit_round_part.lower(
+        sfl.base, state, {"tokens": tok, "labels": tok}, jnp.ones(K),
+        jnp.ones(K), None)
+    weights = sum(v.nbytes for v in jax.tree.leaves(params))
+    consts = _constant_bytes(lowered.as_text())
+    assert consts < weights / 100, (consts, weights)
+    n_args = len(jax.tree.leaves(lowered.args_info))
+    assert n_args >= len(jax.tree.leaves(sfl.base)), n_args

@@ -1,0 +1,127 @@
+"""Ahead-of-time compiles of the main-path Pallas kernels for a described
+TPU v5e chip, at GPT2-S widths.
+
+Nothing runs: each test lowers one kernel entry from shapes alone and
+compiles it with the TPU compiler for a chip that is described, not
+attached, so a block layout or VMEM budget the chip's compiler refuses
+fails here instead of on the chip.  Interpret-mode parity tests cannot
+catch those refusals.  The topology is described inside a module fixture,
+never at import, so only the worker that runs this file loads the TPU
+library.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels.flash_attention import flash_decode, paged_decode
+from repro.kernels.lora_matmul import lora_matmul, lora_matmul_gathered
+
+# GPT2-S (configs/gpt2_s.py): d 768, d_ff 3072, 12 heads of 64, LoRA r 4.
+# Training projections see M = batch x seq = 4 x 512 rows per client;
+# serving decodes 4 slots over a 128-token cache in 16-token pages.
+D_MODEL, D_FF, HEADS, HEAD_DIM, RANK = 768, 3072, 12, 64, 4
+M_TRAIN, SLOTS, MAX_LEN, PAGE = 4 * 512, 4, 128, 16
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure means "cannot describe"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_cache():
+    # a compile for a described chip is written to the persistent cache but
+    # cannot be read back without the chip: keep the cache out of it
+    from jax.experimental.compilation_cache import compilation_cache
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+
+
+def _compile(fn, shapes, sharding):
+    args = [jax.ShapeDtypeStruct(s, d, sharding=sharding) for s, d in shapes]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    return text
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_lora_matmul_forward_compiles(one_chip, no_cache, dtype):
+    def fwd(x, w, a, b):
+        return lora_matmul(x, w, a, b, scale=2.0, interpret=False)
+
+    _compile(fwd, [((M_TRAIN, D_MODEL), dtype), ((D_MODEL, D_FF), dtype),
+                   ((RANK, D_MODEL), dtype), ((D_FF, RANK), dtype)], one_chip)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_lora_matmul_backward_compiles(one_chip, no_cache, dtype):
+    def loss(x, w, a, b):
+        y = lora_matmul(x, w, a, b, scale=2.0, interpret=False)
+        return jnp.sum(y.astype(jnp.float32))
+
+    grad = jax.grad(loss, argnums=(0, 2, 3))
+    _compile(grad, [((M_TRAIN, D_FF), dtype), ((D_FF, D_MODEL), dtype),
+                    ((RANK, D_FF), dtype), ((D_MODEL, RANK), dtype)],
+             one_chip)
+
+
+def test_lora_matmul_gathered_compiles(one_chip, no_cache):
+    pool = 3
+
+    def gathered(x, w, a, b, idx):
+        return lora_matmul_gathered(x, w, a, b, idx, scale=2.0,
+                                    interpret=False)
+
+    _compile(gathered, [((SLOTS, D_MODEL), jnp.float32),
+                        ((D_MODEL, D_FF), jnp.float32),
+                        ((pool, RANK, D_MODEL), jnp.float32),
+                        ((pool, D_FF, RANK), jnp.float32),
+                        ((SLOTS,), jnp.int32)], one_chip)
+
+
+def test_flash_decode_compiles(one_chip, no_cache):
+    def decode(q, k, v, lengths):
+        return flash_decode(q, k, v, lengths, interpret=False)
+
+    _compile(decode, [((SLOTS, 1, HEADS, HEAD_DIM), jnp.float32),
+                      ((SLOTS, MAX_LEN, HEADS, HEAD_DIM), jnp.float32),
+                      ((SLOTS, MAX_LEN, HEADS, HEAD_DIM), jnp.float32),
+                      ((SLOTS,), jnp.int32)], one_chip)
+
+
+@pytest.mark.parametrize("kv_dtype", [jnp.float32, jnp.int8])
+def test_paged_decode_compiles(one_chip, no_cache, kv_dtype):
+    pages = SLOTS * (MAX_LEN // PAGE) + 1
+    shapes = [((SLOTS, 1, HEADS, HEAD_DIM), jnp.float32),
+              ((HEADS, pages, PAGE, HEAD_DIM), kv_dtype),
+              ((HEADS, pages, PAGE, HEAD_DIM), kv_dtype),
+              ((SLOTS,), jnp.int32),
+              ((SLOTS, MAX_LEN // PAGE), jnp.int32)]
+    if kv_dtype == jnp.int8:
+        shapes += [((HEADS,), jnp.float32), ((HEADS,), jnp.float32)]
+
+        def decode(q, kp, vp, lengths, bt, ks, vs):
+            return paged_decode(q, kp, vp, lengths, bt, k_scale=ks,
+                                v_scale=vs, interpret=False)
+    else:
+        def decode(q, kp, vp, lengths, bt):
+            return paged_decode(q, kp, vp, lengths, bt, interpret=False)
+
+    _compile(decode, shapes, one_chip)
