@@ -582,18 +582,20 @@ class SflLLM:
         K, b, S, d = acts.shape
         x = acts.reshape(K * b, S, d)
         positions = jnp.arange(S, dtype=jnp.int32)
-        x, _, aux = stack_mod.apply_stack(
-            cfg, sbase["layers"], x, positions=positions,
-            lora=lora_s, rt=rt, mode="train",
-            rep_gate=(rep_lo, None) if rep_lo is not None else None,
-            lora_scale=self._server_scale)
-        x = apply_norm(cfg, x, sbase["final_norm"])
-        logits = unembed(cfg, sbase["embed"], x)
-        lbl = labels.reshape(K * b, -1)
-        F = logits.shape[1] - lbl.shape[1]
-        if F > 0:
-            logits = logits[:, F:]
-        num, den = _ce_terms(logits, lbl)
+        with jax.named_scope("sfl.server_stack"):
+            x, _, aux = stack_mod.apply_stack(
+                cfg, sbase["layers"], x, positions=positions,
+                lora=lora_s, rt=rt, mode="train",
+                rep_gate=(rep_lo, None) if rep_lo is not None else None,
+                lora_scale=self._server_scale)
+        with jax.named_scope("sfl.head"):
+            x = apply_norm(cfg, x, sbase["final_norm"])
+            logits = unembed(cfg, sbase["embed"], x)
+            lbl = labels.reshape(K * b, -1)
+            F = logits.shape[1] - lbl.shape[1]
+            if F > 0:
+                logits = logits[:, F:]
+            num, den = _ce_terms(logits, lbl)
         return num, den, aux
 
     # ------------------------------------------------------------------
@@ -683,7 +685,13 @@ class SflLLM:
                 fwd_k, mesh=self.mesh,
                 in_specs=(P(CLIENT_AXIS),) * (n_in + 1) + (P(),),
                 out_specs=P(CLIENT_AXIS), check_vma=False)
-        fwd = lambda ls: fwd_k(ls, *client_args, base["client"])
+
+        def fwd(ls):
+            # inside the vjp, so client BP (client_vjp below) carries the
+            # scope as transpose(jvp(sfl.client))
+            with jax.named_scope("sfl.client"):
+                return fwd_k(ls, *client_args, base["client"])
+
         (acts, client_aux), client_vjp = jax.vjp(fwd, state.lora_client)
 
         # boundary quantization (repro.precision): the uploaded payload is
@@ -702,8 +710,9 @@ class SflLLM:
             key_a = jax.random.fold_in(base_key, 0)
             key_g = jax.random.fold_in(base_key, 1)
         if act_bits is not None:
-            acts, new_err_act = fake_quant(acts, act_bits, key=key_a,
-                                           err=state.err_act)
+            with jax.named_scope("sfl.boundary"):
+                acts, new_err_act = fake_quant(acts, act_bits, key=key_a,
+                                               err=state.err_act)
 
         # (b) upload (s_k, y_k) — wireless; modeled in core.latency --------
         # (c,d) server FP + BP on the pooled activations --------------------
@@ -721,8 +730,10 @@ class SflLLM:
         # the downloaded gradient is quantized the same way the uploaded
         # activation was (static config-wide grad_bits, per-client scale)
         if self._grad_bits is not None:
-            g_acts, new_err_grad = fake_quant(g_acts, self._grad_bits,
-                                              key=key_g, err=state.err_grad)
+            with jax.named_scope("sfl.boundary"):
+                g_acts, new_err_grad = fake_quant(g_acts, self._grad_bits,
+                                                  key=key_g,
+                                                  err=state.err_grad)
         # client-side MoE aux loss contributes through the aux cotangent
         # (masked per client under partial participation)
         aux_seed = jnp.full_like(client_aux, self.aux_coef)
@@ -730,42 +741,43 @@ class SflLLM:
             aux_seed = aux_seed * part
         (g_client,) = client_vjp((g_acts, aux_seed))
 
-        upd_s, opt_s = self.opt.update(g_server, state.opt_server,
-                                       state.lora_server)
-        upd_c, opt_c = self.opt.update(g_client, state.opt_client,
-                                       state.lora_client)
-        if masks is not None:
-            # masked updates: dead rows/cols of the padded adapters stay
-            # exactly zero no matter what the optimizer does with eps /
-            # weight decay
-            upd_c = jax.tree.map(lambda u, m: u * m.astype(u.dtype),
-                                 upd_c, masks)
-        if part is not None:
-            # a dropped client's adapter AND optimizer moments freeze for
-            # the round: zero grads alone would still decay Adam moments
-            pcol = lambda v: part.reshape((-1,) + (1,) * (v.ndim - 1))
-            upd_c = jax.tree.map(lambda u: u * pcol(u).astype(u.dtype),
-                                 upd_c)
-            opt_c = jax.tree.map(
-                lambda n, o: n if n.ndim == 0
-                else jnp.where(pcol(n) > 0, n, o),
-                opt_c, state.opt_client)
-            # an empty round (every client past the deadline) freezes the
-            # server as well — nobody uploaded, nothing trained
-            any_p = part.sum() > 0
-            upd_s = jax.tree.map(
-                lambda u: jnp.where(any_p, u, jnp.zeros_like(u)), upd_s)
-            opt_s = jax.tree.map(lambda n, o: jnp.where(any_p, n, o),
-                                 opt_s, state.opt_server)
-        new = SflState(
-            lora_client=apply_updates(state.lora_client, upd_c),
-            lora_server=apply_updates(state.lora_server, upd_s),
-            opt_client=opt_c,
-            opt_server=opt_s,
-            step=state.step + 1,
-            err_act=new_err_act,
-            err_grad=new_err_grad,
-        )
+        with jax.named_scope("sfl.optimizer"):
+            upd_s, opt_s = self.opt.update(g_server, state.opt_server,
+                                           state.lora_server)
+            upd_c, opt_c = self.opt.update(g_client, state.opt_client,
+                                           state.lora_client)
+            if masks is not None:
+                # masked updates: dead rows/cols of the padded adapters stay
+                # exactly zero no matter what the optimizer does with eps /
+                # weight decay
+                upd_c = jax.tree.map(lambda u, m: u * m.astype(u.dtype),
+                                     upd_c, masks)
+            if part is not None:
+                # a dropped client's adapter AND optimizer moments freeze for
+                # the round: zero grads alone would still decay Adam moments
+                pcol = lambda v: part.reshape((-1,) + (1,) * (v.ndim - 1))
+                upd_c = jax.tree.map(lambda u: u * pcol(u).astype(u.dtype),
+                                     upd_c)
+                opt_c = jax.tree.map(
+                    lambda n, o: n if n.ndim == 0
+                    else jnp.where(pcol(n) > 0, n, o),
+                    opt_c, state.opt_client)
+                # an empty round (every client past the deadline) freezes the
+                # server as well — nobody uploaded, nothing trained
+                any_p = part.sum() > 0
+                upd_s = jax.tree.map(
+                    lambda u: jnp.where(any_p, u, jnp.zeros_like(u)), upd_s)
+                opt_s = jax.tree.map(lambda n, o: jnp.where(any_p, n, o),
+                                     opt_s, state.opt_server)
+            new = SflState(
+                lora_client=apply_updates(state.lora_client, upd_c),
+                lora_server=apply_updates(state.lora_server, upd_s),
+                opt_client=opt_c,
+                opt_server=opt_s,
+                step=state.step + 1,
+                err_act=new_err_act,
+                err_grad=new_err_grad,
+            )
         return new, {"loss": loss, "total": total}
 
     # ------------------------------------------------------------------
@@ -869,16 +881,17 @@ class SflLLM:
         new, metrics = jax.lax.scan(
             lambda st, b: self._step_impl(base, st, b, cfg_dyn, part),
             state, round_batches)
-        if byz is not None:
-            # corrupted uploads: the radio payload between client and
-            # federated server — optimizer moments stay the client's own
-            new = SflState(
-                lora_client=corrupt_updates(new.lora_client, ref, byz),
-                lora_server=new.lora_server, opt_client=new.opt_client,
-                opt_server=new.opt_server, step=new.step,
-                err_act=new.err_act, err_grad=new.err_grad)
-        new, scores = self._aggregate_impl(new, weights, part, masks,
-                                           robust, ref)
+        with jax.named_scope("sfl.fedavg"):
+            if byz is not None:
+                # corrupted uploads: the radio payload between client and
+                # federated server — optimizer moments stay the client's own
+                new = SflState(
+                    lora_client=corrupt_updates(new.lora_client, ref, byz),
+                    lora_server=new.lora_server, opt_client=new.opt_client,
+                    opt_server=new.opt_server, step=new.step,
+                    err_act=new.err_act, err_grad=new.err_grad)
+            new, scores = self._aggregate_impl(new, weights, part, masks,
+                                               robust, ref)
         if poison is not None:
             # deterministic fault injection: poison > 0 NaNs the aggregated
             # server adapter; poison == 0 keeps the clean values bit-exactly
@@ -889,9 +902,10 @@ class SflLLM:
                                         v), new.lora_server),
                 opt_client=new.opt_client, opt_server=new.opt_server,
                 step=new.step, err_act=new.err_act, err_grad=new.err_grad)
-        finite = tree_all_finite(new)
-        state = jax.tree.map(lambda n, o: jnp.where(finite, n, o),
-                             new, state)
+        with jax.named_scope("sfl.commit"):
+            finite = tree_all_finite(new)
+            state = jax.tree.map(lambda n, o: jnp.where(finite, n, o),
+                                 new, state)
         metrics = dict(metrics, participation=part, rolled_back=~finite)
         if scores is not None:
             metrics["anomaly_scores"] = scores
@@ -958,33 +972,40 @@ class SflLLM:
         run ONE compiled graph (mask + optional config arrays are traced
         inputs; a static round is the all-ones mask), so mixing static and
         dynamic rounds never retraces as long as the re-allocation arrays
-        are either always or never supplied."""
-        batches = {k: jnp.asarray(v) for k, v in round_batches.items()
-                   if v is not None}
-        weights = jnp.asarray(list(sample_counts), jnp.float32)
-        if self.mesh is not None:
-            from ..sharding.specs import round_batch_shardings
-            batches = jax.device_put(
-                batches, round_batch_shardings(batches, self.mesh))
-        dyn = RoundDynamics() if dynamics is None else dynamics
-        part = self._participation_for(dyn, batches)
-        cfg_dyn = None
-        if (dyn.rep_hi is not None or dyn.slot_masks is not None
-                or dyn.scales is not None or dyn.act_bits is not None):
-            cfg_dyn = {"rep_hi": dyn.rep_hi, "slot_masks": dyn.slot_masks,
-                       "scales": dyn.scales, "act_bits": dyn.act_bits}
-        state = self._ensure_err_state(
-            state, batches["tokens"].shape[-2:],
-            batches.get("frontend_emb"),
-            armed_act=self._act_bits is not None or dyn.act_bits is not None)
-        if self.mesh is not None:
-            from ..sharding.specs import round_dynamics_shardings
-            part, cfg_dyn = jax.device_put(
-                (part, cfg_dyn),
-                round_dynamics_shardings((part, cfg_dyn), self.mesh))
-        return self._jit_round_part(self.base, state, batches, weights, part,
-                                    cfg_dyn, dyn.poison, dyn.robust,
-                                    dyn.byzantine)
+        are either always or never supplied.
+
+        Profiler spans (no-ops unless a trace is running): ``sfl.put``
+        (batches, weights and the participation mask to the device) and
+        ``sfl.enqueue`` (the compiled round's dispatch)."""
+        with jax.profiler.TraceAnnotation("sfl.put"):
+            batches = {k: jnp.asarray(v) for k, v in round_batches.items()
+                       if v is not None}
+            weights = jnp.asarray(list(sample_counts), jnp.float32)
+            if self.mesh is not None:
+                from ..sharding.specs import round_batch_shardings
+                batches = jax.device_put(
+                    batches, round_batch_shardings(batches, self.mesh))
+            dyn = RoundDynamics() if dynamics is None else dynamics
+            part = self._participation_for(dyn, batches)
+            cfg_dyn = None
+            if (dyn.rep_hi is not None or dyn.slot_masks is not None
+                    or dyn.scales is not None or dyn.act_bits is not None):
+                cfg_dyn = {"rep_hi": dyn.rep_hi, "slot_masks": dyn.slot_masks,
+                           "scales": dyn.scales, "act_bits": dyn.act_bits}
+            state = self._ensure_err_state(
+                state, batches["tokens"].shape[-2:],
+                batches.get("frontend_emb"),
+                armed_act=(self._act_bits is not None
+                           or dyn.act_bits is not None))
+            if self.mesh is not None:
+                from ..sharding.specs import round_dynamics_shardings
+                part, cfg_dyn = jax.device_put(
+                    (part, cfg_dyn),
+                    round_dynamics_shardings((part, cfg_dyn), self.mesh))
+        with jax.profiler.TraceAnnotation("sfl.enqueue"):
+            return self._jit_round_part(self.base, state, batches, weights,
+                                        part, cfg_dyn, dyn.poison, dyn.robust,
+                                        dyn.byzantine)
 
     def allocation_dynamics(self, ell_k, rank_k,
                             bits_k=None) -> Dict[str, Any]:

@@ -11,6 +11,10 @@ outer loop once:
   dispatch — we only block on the loss floats after staging the next xs);
 * logging / loss history;
 * checkpoint hooks (``checkpoint.save_pytree`` every N rounds);
+* profiler spans on the host's work of each round (``train.round`` and,
+  inside it, ``train.dispatch``, ``train.stage``, ``train.pull``,
+  ``train.callback``, ``train.checkpoint``): ``jax.profiler`` annotations,
+  which do nothing unless a trace is running;
 * modeled per-round wall clock over the wireless network (core.latency
   eq. 16-17), accumulated next to the measured wall clock so runs report
   both "what the hardware did" and "what the paper's network would take".
@@ -613,78 +617,90 @@ class Trainer:
                                            self.local_steps)
                      if self.round_latency else 0.0)
         prev_wall = history.wall_seconds
-        t0 = time.time()
+        t0 = time.perf_counter()
         # replay the consumed data stream so round start_round sees exactly
         # the batches it would have in the uninterrupted run
         for _ in range(start_round):
             stack_rounds(data_iter, self.local_steps)
-        staged = stack_rounds(data_iter, self.local_steps)
+        with jax.profiler.TraceAnnotation("train.stage"):
+            staged = stack_rounds(data_iter, self.local_steps)
         for e in range(start_round, global_rounds):
-            if self.dynamics is not None:
-                dyn, info = self.dynamics.round_dynamics()
-                state, metrics = self.algo.run_round(state, staged,
-                                                     dynamics=dyn)
-            else:
-                dyn, info = None, None
-                state, metrics = self.algo.run_round(state, staged)
-            if e + 1 < global_rounds:       # prefetch while the device runs
-                staged = stack_rounds(data_iter, self.local_steps)
-            losses = np.asarray(jax.device_get(metrics["loss"]),
-                                np.float64).reshape(-1)
-            history.losses.extend(float(x) for x in losses)
-            history.round_losses.append(float(losses.mean()))
-            rb = (metrics.get("rolled_back")
-                  if isinstance(metrics, dict) else None)
-            if rb is not None and bool(jax.device_get(rb)):
-                history.rolled_back_rounds.append(e)
-            scores = (metrics.get("anomaly_scores")
+            with jax.profiler.StepTraceAnnotation("train.round", step_num=e):
+                if self.dynamics is not None:
+                    dyn, info = self.dynamics.round_dynamics()
+                    kw = {"dynamics": dyn}
+                else:
+                    info, kw = None, {}
+                with jax.profiler.TraceAnnotation("train.dispatch"):
+                    state, metrics = self.algo.run_round(state, staged, **kw)
+                if e + 1 < global_rounds:       # prefetch while the device runs
+                    with jax.profiler.TraceAnnotation("train.stage"):
+                        staged = stack_rounds(data_iter, self.local_steps)
+                rb = (metrics.get("rolled_back")
                       if isinstance(metrics, dict) else None)
-            if scores is not None:
-                s_host = {k: np.asarray(jax.device_get(v),
-                                        np.float64).tolist()
-                          for k, v in scores.items()}
-                history.anomaly_scores.append(s_host)
+                # the host's waits on the round just dispatched: its losses
+                # and its rollback flag
+                with jax.profiler.TraceAnnotation("train.pull"):
+                    loss = jax.device_get(metrics["loss"])
+                    rolled_back = rb is not None and bool(jax.device_get(rb))
+                losses = np.asarray(loss, np.float64).reshape(-1)
+                history.losses.extend(float(x) for x in losses)
+                history.round_losses.append(float(losses.mean()))
+                if rolled_back:
+                    history.rolled_back_rounds.append(e)
+                scores = (metrics.get("anomaly_scores")
+                          if isinstance(metrics, dict) else None)
+                if scores is not None:
+                    s_host = {k: np.asarray(jax.device_get(v),
+                                            np.float64).tolist()
+                              for k, v in scores.items()}
+                    history.anomaly_scores.append(s_host)
+                    if info is not None:
+                        # close the loop: this round's scores update client
+                        # reputations, which shape the NEXT round's mask
+                        self.dynamics.observe_scores(s_host,
+                                                     info["participation"])
+                if info is not None and "quarantined" in info:
+                    history.quarantined.append(info["quarantined"])
                 if info is not None:
-                    # close the loop: this round's scores update client
-                    # reputations, which shape the NEXT round's mask
-                    self.dynamics.observe_scores(s_host,
-                                                 info["participation"])
-            if info is not None and "quarantined" in info:
-                history.quarantined.append(info["quarantined"])
-            if info is not None:
-                history.modeled_seconds += info["round_seconds"]
-                history.participation.append(info["participation"])
-                history.modeled_delays.append(info["modeled_delay"])
-                if info["realloc"]:
-                    history.realloc_rounds.append(e)
-            else:
-                history.modeled_seconds += per_round
-            if self.log_every and (e + 1) % self.log_every == 0:
-                msg = (f"round {e + 1}/{global_rounds}  "
-                       f"loss {losses[-1]:.4f}")
-                if per_round or info is not None:
-                    msg += f"  modeled {history.modeled_seconds:.1f}s"
-                if info is not None:
-                    msg += f"  clients {sum(info['participation'])}/" \
-                           f"{len(info['participation'])}"
+                    history.modeled_seconds += info["round_seconds"]
+                    history.participation.append(info["participation"])
+                    history.modeled_delays.append(info["modeled_delay"])
                     if info["realloc"]:
-                        msg += "  [re-allocated]"
-                print(msg)
-            if (self.checkpoint_path and self.checkpoint_every
-                    and (e + 1) % self.checkpoint_every == 0):
-                self._save(state)
-            if (self.episode_path and self.episode_every
-                    and (e + 1) % self.episode_every == 0):
-                history.wall_seconds = prev_wall + (time.time() - t0)
-                self._save_episode(state, e + 1, history)
-            if self.callback is not None:
-                self.callback(e, state, history)
-        history.wall_seconds = prev_wall + (time.time() - t0)
+                        history.realloc_rounds.append(e)
+                else:
+                    history.modeled_seconds += per_round
+                if self.log_every and (e + 1) % self.log_every == 0:
+                    msg = (f"round {e + 1}/{global_rounds}  "
+                           f"loss {losses[-1]:.4f}")
+                    if per_round or info is not None:
+                        msg += f"  modeled {history.modeled_seconds:.1f}s"
+                    if info is not None:
+                        msg += f"  clients {sum(info['participation'])}/" \
+                               f"{len(info['participation'])}"
+                        if info["realloc"]:
+                            msg += "  [re-allocated]"
+                    print(msg)
+                if (self.checkpoint_path and self.checkpoint_every
+                        and (e + 1) % self.checkpoint_every == 0):
+                    with jax.profiler.TraceAnnotation("train.checkpoint"):
+                        self._save(state)
+                if (self.episode_path and self.episode_every
+                        and (e + 1) % self.episode_every == 0):
+                    history.wall_seconds = (prev_wall
+                                            + time.perf_counter() - t0)
+                    with jax.profiler.TraceAnnotation("train.checkpoint"):
+                        self._save_episode(state, e + 1, history)
+                if self.callback is not None:
+                    with jax.profiler.TraceAnnotation("train.callback"):
+                        self.callback(e, state, history)
+        history.wall_seconds = prev_wall + (time.perf_counter() - t0)
         steps = len(history.losses)
         if history.wall_seconds > 0:
             history.steps_per_sec = steps / history.wall_seconds
         if self.checkpoint_path and not self.checkpoint_every:
-            self._save(state)
+            with jax.profiler.TraceAnnotation("train.checkpoint"):
+                self._save(state)
         return state, history
 
     def _save(self, state) -> None:

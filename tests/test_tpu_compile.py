@@ -125,3 +125,49 @@ def test_paged_decode_compiles(one_chip, no_cache, kv_dtype):
             return paged_decode(q, kp, vp, lengths, bt, interpret=False)
 
     _compile(decode, shapes, one_chip)
+
+
+def test_sfl_round_keeps_kernel_names_under_phase_scopes(one_chip, no_cache,
+                                                         monkeypatch):
+    """The tiny SFL round with the fused kernels forced on: the phase
+    scopes sit above the kernels, so each is still an instruction named
+    ``closed_call`` (a scope directly around one would rename it, and the
+    benchmark counts kernels by that name), and the phases reach the
+    compiled fusions' metadata.  Per local step of a 2-layer round split
+    at 1: 2L forward, 2(L-1) dX and 4L rank reductions."""
+    import re
+
+    from repro.configs import TrainConfig, get_arch
+    from repro.core.sfl import SflLLM
+    from repro.kernels import backend
+    from repro.models import layers
+    from repro.models.model import init_lora_stack, init_params
+    from repro.optim import adamw
+
+    monkeypatch.setattr(layers, "FUSED_DENSE_BACKENDS", ("tpu", "cpu"))
+    monkeypatch.setattr(backend, "auto_interpret", lambda: False)
+    L, K, b, S, steps = 2, 2, 2, 128, 2
+    cfg = get_arch("gpt2-s").reduced(num_layers=L, d_model=128, vocab=512)
+    sfl = SflLLM(cfg, init_params(cfg, jax.random.key(0)), ell_c=1,
+                 train_cfg=TrainConfig(num_clients=K, batch_size=b,
+                                       local_steps=steps),
+                 optimizer=adamw(1e-3))
+    state = sfl.init_state(init_lora_stack(cfg, jax.random.key(1)))
+
+    def shaped(tree):
+        return jax.tree.map(lambda v: jax.ShapeDtypeStruct(
+            v.shape, v.dtype, sharding=one_chip), tree)
+
+    ids = jax.ShapeDtypeStruct((steps, K, b, S), jnp.int32,
+                               sharding=one_chip)
+    per_client = jax.ShapeDtypeStruct((K,), jnp.float32, sharding=one_chip)
+    text = sfl._jit_round_part.lower(
+        shaped(sfl.base), shaped(state), {"tokens": ids, "labels": ids},
+        per_client, per_client, None).compile().as_text()
+    kernels = re.findall(r"^\s*(?:ROOT )?%?closed_call[.\d]* = .* "
+                         r"custom-call\(", text, re.M)
+    assert len(kernels) == 2 * L + 2 * (L - 1) + 4 * L
+    fusion_meta = "\n".join(re.findall(r"^\s*(?:ROOT )?%?[\w.-]*fusion[\w.-]* = "
+                                       r".*op_name=\"([^\"]+)\"", text, re.M))
+    for phase in ("sfl.server_stack", "sfl.client"):
+        assert phase in fusion_meta
