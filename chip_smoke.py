@@ -65,7 +65,8 @@ SHARDED_VS_ONE_LOSS = 2e-3
 # reductions of 64 to 3072 terms of unit-scale data.
 KERNEL_REL_ERR = {"lora_matmul": 2e-2, "lora_matmul_bwd": 2e-2,
                   "lora_matmul_gathered": 2e-2, "flash_decode": 2e-2,
-                  "paged_decode": 2e-2}
+                  "paged_decode": 2e-2, "flash_attention_train": 2e-2,
+                  "flash_attention_train_bwd": 2e-2}
 
 
 def check(ok: bool, msg: str) -> None:
@@ -389,9 +390,11 @@ def phase_kernels(cfg, dev) -> None:
     import jax
     import jax.numpy as jnp
 
-    from repro.kernels.flash_attention import flash_decode, paged_decode
+    from repro.kernels.flash_attention import (flash_attention_train,
+                                               flash_decode, paged_decode)
     from repro.kernels.lora_matmul import (lora_matmul, lora_matmul_gathered,
                                            lora_matmul_ref)
+    from repro.models.attention import naive_attention
 
     d, ff, H = cfg.d_model, cfg.d_ff, cfg.num_heads
     hd = d // H
@@ -457,6 +460,25 @@ def phase_kernels(cfg, dev) -> None:
     with jax.default_matmul_precision("highest"):
         ref = paged_decode(q, kp, vp, lens, bt, use_kernel=False)
     errs["paged_decode"] = rel(got, ref)
+
+    # training attention: the pooled batch of one round step, causal
+    Bt, S = TRAIN["clients"] * TRAIN["batch"], TRAIN["seq"]
+    qa, ka, va, ga = (jax.random.normal(kk, (Bt, S, H, hd), jnp.float32)
+                      for kk in jax.random.split(jax.random.key(SEED + 8), 4))
+    pos = jnp.arange(S)
+    attn = lambda q, k, v: flash_attention_train(q, k, v, use_kernel=True)
+    attn_ref = lambda q, k, v: naive_attention(q, k, v, pos, pos)
+    attn_loss = lambda fn: lambda q, k, v, g: jnp.sum(fn(q, k, v) * g)
+    got = jax.jit(attn)(qa, ka, va)
+    grads = jax.jit(jax.grad(attn_loss(attn), argnums=(0, 1, 2)))(
+        qa, ka, va, ga)
+    with jax.default_matmul_precision("highest"):
+        ref = jax.jit(attn_ref)(qa, ka, va)
+        grads_ref = jax.jit(jax.grad(attn_loss(attn_ref), argnums=(0, 1, 2)))(
+            qa, ka, va, ga)
+    errs["flash_attention_train"] = rel(got, ref)
+    errs["flash_attention_train_bwd"] = max(rel(u, v) for u, v in
+                                            zip(grads, grads_ref))
 
     for name, e in errs.items():
         print(f"[kernels] {name}: max|kernel - oracle| / max|oracle| = "

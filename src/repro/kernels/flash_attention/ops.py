@@ -1,50 +1,131 @@
-"""jit'd wrapper: (B, S, H, D) model layout -> kernel layout + padding."""
+"""Entries of the attention kernels: the (B, S, H, D) model layout -> the
+kernels' merged-head layout (a reshape) + padding, backend dispatch, and the
+training custom VJP."""
 from __future__ import annotations
 
 import functools
+from typing import Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 
 from .. import backend
-from ..backend import auto_interpret
 from .decode import flash_decode_kernel, flash_decode_q8_kernel
-from .kernel import flash_attention_kernel
+from .kernel import flash_attention_bwd_kernels, flash_attention_kernel
 from .paged_decode import paged_decode_kernel, paged_decode_q8_kernel
 from .ref import (flash_attention_ref, flash_decode_q8_ref, flash_decode_ref,
                   paged_decode_q8_ref, paged_decode_ref)
-from .tune import best_decode_block, best_paged_block
+from .tune import best_decode_block, best_paged_block, best_train_blocks
 
 
-@functools.partial(jax.jit, static_argnames=("window", "bq", "bk",
-                                             "interpret", "use_kernel"))
-def flash_attention(q, k, v, *, window: int = 0, bq: int = 256, bk: int = 256,
-                    interpret: "bool | None" = None, use_kernel: bool = True):
-    """Causal GQA attention.  q: (B, Sq, H, D); k/v: (B, Sk, KH, D) —
-    the model layout of ``repro.models.attention``.
+def _pad_seq(x, n: int):
+    return jnp.pad(x, ((0, 0), (0, n), (0, 0))) if n else x
 
-    ``interpret=None`` auto-detects: the native kernel on TPU, the Pallas
-    interpreter elsewhere — callers never need to know the flag."""
-    if interpret is None:
-        interpret = auto_interpret()
+
+def _oracle(q, k, v, window: int):
+    """``flash_attention_ref`` on the model layout."""
+    t = lambda x: x.transpose(0, 2, 1, 3)
+    return t(flash_attention_ref(t(q), t(k), t(v), window=window))
+
+
+# ---------------------------------------------------------------------------
+# training: forward + backward kernels behind one custom VJP
+# ---------------------------------------------------------------------------
+
+class _TrainCfg(NamedTuple):
+    """Static kernel config: the custom VJP's nondiff argument."""
+    head_dim: int
+    window: int
+    seq_k: int
+    q_offset: int
+    bq: int
+    bk: int
+    kv_heads: int
+    mxu_dtype: str
+    interpret: bool
+
+    def kw(self) -> dict:
+        kw = self._asdict()
+        kw["mxu_dtype"] = jnp.dtype(self.mxu_dtype)
+        return kw
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _flash_train(cfg: _TrainCfg, q, k, v):
+    return flash_attention_kernel(q, k, v, **cfg.kw())[0]
+
+
+def _flash_train_fwd(cfg: _TrainCfg, q, k, v):
+    o, lse = flash_attention_kernel(q, k, v, **cfg.kw())
+    return o, (q, k, v, o, lse)
+
+
+def _flash_train_bwd(cfg: _TrainCfg, res, do):
+    q, k, v, o, lse = res
+    B, S, HD = q.shape
+    delta = jnp.sum((o.astype(jnp.float32) * do.astype(jnp.float32)).reshape(
+        B, S, HD // cfg.head_dim, cfg.head_dim), axis=-1)
+    return flash_attention_bwd_kernels(q, k, v, do, lse,
+                                       delta.transpose(0, 2, 1)[:, :, None],
+                                       **cfg.kw())
+
+
+_flash_train.defvjp(_flash_train_fwd, _flash_train_bwd)
+
+
+def mxu_dtype_for(dtype):
+    """Operand dtype of the training kernels' tile matmuls: bfloat16 for
+    float32 inputs at the default matmul precision (what XLA's own f32
+    dots do on a TPU), the input dtype otherwise."""
+    default = jax.config.jax_default_matmul_precision in (
+        None, "default", "bfloat16", "fastest")
+    if jnp.dtype(dtype) == jnp.float32 and default:
+        return jnp.dtype(jnp.bfloat16)
+    return jnp.dtype(dtype)
+
+
+def flash_attention_train(q, k, v, *, window: int = 0,
+                          ref: "Optional[Callable[[], jax.Array]]" = None,
+                          bq: "int | None" = None, bk: "int | None" = None,
+                          kv_heads: "int | None" = None,
+                          interpret: "bool | None" = None,
+                          use_kernel: "bool | None" = None):
+    """Differentiable causal GQA attention for training and prefill.
+
+    q: (B, Sq, H, D); k/v: (B, Sk, KH, D) — the model layout — with q
+    aligned at the end of k (q row r at position Sk - Sq + r).  On the
+    kernel path the forward and both backward passes are Pallas kernels
+    behind one custom VJP, on the model layout with the heads merged (no
+    transpose): no (Sq, Sk) tensor reaches HBM, and the backward keeps q,
+    k, v, the output and the f32 log-sum-exp.
+
+    Dispatch follows ``kernels.backend.dispatch`` under the name
+    ``flash_attention_train``: the kernels on TPU, ``ref`` (a zero-argument
+    thunk; default the f32 jnp oracle) elsewhere, and an explicit
+    ``interpret`` forces the kernels.  Blocks default to
+    ``tune.best_train_blocks``; the tile matmuls take ``mxu_dtype_for``
+    operands."""
     B, Sq, H, D = q.shape
     Sk, KH = k.shape[1], k.shape[2]
-    qt = q.transpose(0, 2, 1, 3)
-    kt = k.transpose(0, 2, 1, 3)
-    vt = v.transpose(0, 2, 1, 3)
-    if not use_kernel:
-        o = flash_attention_ref(qt, kt, vt, window=window)
-        return o.transpose(0, 2, 1, 3)
-    bq_, bk_ = min(bq, Sq), min(bk, Sk)
-    pq, pk = (-Sq) % bq_, (-Sk) % bk_
-    if pq or pk:
-        qt = jnp.pad(qt, ((0, 0), (0, 0), (0, pq), (0, 0)))
-        kt = jnp.pad(kt, ((0, 0), (0, 0), (0, pk), (0, 0)))
-        vt = jnp.pad(vt, ((0, 0), (0, 0), (0, pk), (0, 0)))
-    o = flash_attention_kernel(qt, kt, vt, window=window, seq_k=Sk,
-                               q_offset=max(Sk - Sq, 0),
-                               bq=bq_, bk=bk_, interpret=interpret)
-    return o[:, :, :Sq].transpose(0, 2, 1, 3)
+
+    def _ref():
+        return ref() if ref is not None else _oracle(q, k, v, window)
+
+    def _kern(interp: bool):
+        tq, tk, tkv = best_train_blocks(B, H, KH, Sq, Sk, D, q.dtype)
+        tq, tk, tkv = bq or tq, bk or tk, kv_heads or tkv
+        pq, pk = (-Sq) % tq, (-Sk) % tk
+        cfg = _TrainCfg(int(D), int(window), int(Sk), max(Sk - Sq, 0),
+                        int(tq), int(tk), int(tkv),
+                        mxu_dtype_for(q.dtype).name,
+                        bool(interp))
+        o = _flash_train(cfg, _pad_seq(q.reshape(B, Sq, H * D), pq),
+                         _pad_seq(k.reshape(B, Sk, KH * D), pk),
+                         _pad_seq(v.reshape(B, Sk, KH * D), pk))
+        return o[:, :Sq].reshape(B, Sq, H, D)
+
+    return backend.dispatch("flash_attention_train", kernel=_kern, ref=_ref,
+                            interpret=interpret, use_kernel=use_kernel)
 
 
 @functools.partial(jax.jit, static_argnames=("window", "bk", "interpret",

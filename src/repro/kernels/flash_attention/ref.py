@@ -93,3 +93,73 @@ def flash_attention_ref(q, k, v, *, window: int = 0, seq_k: int = 0):
     p = p / jnp.maximum(p.sum(-1, keepdims=True), 1e-30)
     o = jnp.einsum("bhqk,bhkd->bhqd", p, vr.astype(jnp.float32))
     return o.astype(q.dtype)
+
+
+def _causal_mask(Sq: int, Sk: int, window: int):
+    q_pos = (Sk - Sq) + jnp.arange(Sq)
+    k_pos = jnp.arange(Sk)
+    mask = k_pos[None, :] <= q_pos[:, None]
+    if window:
+        mask &= (q_pos[:, None] - k_pos[None, :]) < window
+    return mask
+
+
+def _scaled_q(q, mxu_dtype):
+    """q times D^-1/2, rounded to the operand dtype after the scaling."""
+    return (q.astype(jnp.float32) * q.shape[-1] ** -0.5).astype(mxu_dtype)
+
+
+def _scores(q, k, mxu_dtype, window):
+    """(B, H, Sq, Sk) f32 scores from mxu-dtype operands, masked; and the
+    mask.  GQA by repeating KV heads."""
+    H, Sq = q.shape[1], q.shape[2]
+    KH, Sk = k.shape[1], k.shape[2]
+    kr = jnp.repeat(k, H // KH, axis=1)
+    s = jnp.einsum("bhqd,bhkd->bhqk", _scaled_q(q, mxu_dtype),
+                   kr.astype(mxu_dtype), preferred_element_type=jnp.float32)
+    mask = _causal_mask(Sq, Sk, window)[None, None]
+    return jnp.where(mask, s, NEG_INF), mask
+
+
+def flash_attention_train_ref(q, k, v, *, window: int = 0, mxu_dtype=None):
+    """(o, lse) under the training kernels' contract: q (B, H, Sq, D), k/v
+    (B, KH, Sk, D), causal with q aligned at the end of k; the score and
+    PV matmuls take ``mxu_dtype`` operands (default: the input dtype; q
+    scaled by D^-1/2 before its cast) with f32 accumulation, softmax
+    statistics in f32.  lse: f32 (B, H, Sq)."""
+    mxu_dtype = mxu_dtype or q.dtype
+    H, KH = q.shape[1], k.shape[1]
+    s, mask = _scores(q, k, mxu_dtype, window)
+    m = s.max(-1, keepdims=True)
+    p = jnp.where(mask, jnp.exp(s - m), 0.0)
+    l = p.sum(-1, keepdims=True)
+    vr = jnp.repeat(v, H // KH, axis=1)
+    o = jnp.einsum("bhqk,bhkd->bhqd", p.astype(mxu_dtype),
+                   vr.astype(mxu_dtype),
+                   preferred_element_type=jnp.float32) / l
+    return o.astype(q.dtype), (m + jnp.log(l))[..., 0]
+
+
+def flash_attention_train_bwd_ref(q, k, v, o, lse, do, *, window: int = 0,
+                                  mxu_dtype=None):
+    """(dq, dk, dv) under the same contract, p recomputed from lse as the
+    backward kernels do; KV gradients summed over each head group."""
+    mxu_dtype = mxu_dtype or q.dtype
+    B, H, Sq, D = q.shape
+    KH, Sk = k.shape[1], k.shape[2]
+    G = H // KH
+    s, mask = _scores(q, k, mxu_dtype, window)
+    p = jnp.where(mask, jnp.exp(s - lse[..., None]), 0.0)
+    f32 = dict(preferred_element_type=jnp.float32)
+    c = lambda x: x.astype(mxu_dtype)
+    kr = jnp.repeat(k, G, axis=1)
+    vr = jnp.repeat(v, G, axis=1)
+    dv = jnp.einsum("bhqk,bhqd->bhkd", c(p), c(do), **f32)
+    dp = jnp.einsum("bhqd,bhkd->bhqk", c(do), c(vr), **f32)
+    delta = jnp.sum(o.astype(jnp.float32) * do.astype(jnp.float32), -1)
+    ds = p * (dp - delta[..., None])
+    dq = jnp.einsum("bhqk,bhkd->bhqd", c(ds), c(kr), **f32) * D ** -0.5
+    dk = jnp.einsum("bhqk,bhqd->bhkd", c(ds), _scaled_q(q, mxu_dtype), **f32)
+    group = lambda t: t.reshape(B, KH, G, Sk, D).sum(2)
+    return (dq.astype(q.dtype), group(dk).astype(k.dtype),
+            group(dv).astype(v.dtype))

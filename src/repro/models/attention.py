@@ -262,23 +262,48 @@ def online_attention(q, k, v, q_pos, k_pos, *, window: int = 0,
                             min(kv_chunk, Sk), s_low_precision)
 
 
+def _kernel_covers(q, k, v, causal_prefix: bool, s_low_precision: bool) -> bool:
+    """Whether ``kernels.flash_attention.flash_attention_train`` computes
+    this call: causal self-attention over positions 0..S-1 (what
+    ``causal_prefix`` asserts), any window, f32 or bf16 operands of one
+    dtype, and a head size the MXU tiles take.  ``s_low_precision`` asks
+    for bf16-accumulated scores, which the kernel does not do."""
+    dtypes = {q.dtype, k.dtype, v.dtype}
+    return (causal_prefix and q.shape[1] == k.shape[1]
+            and not s_low_precision
+            and len(dtypes) == 1 and dtypes <= {jnp.dtype(jnp.float32),
+                                                jnp.dtype(jnp.bfloat16)}
+            and q.shape[-1] % 8 == 0 and q.shape[-1] <= 256)
+
+
 def run_attention(q, k, v, q_pos, k_pos, *, impl: str = "chunked",
                   window: int = 0, kv_chunk: int = 512,
                   q_chunk: int = 0, causal_prefix: bool = False,
                   s_low_precision: bool = False) -> jax.Array:
+    """``impl="chunked"`` takes the fused flash-attention kernels (forward
+    and backward) where the backend runs them natively and the call is one
+    they cover (``_kernel_covers``).  Otherwise, and on CPU, the jnp path
+    below runs: ``kv_chunk`` and ``q_chunk`` only shape it."""
     if impl == "naive":
         return naive_attention(q, k, v, q_pos, k_pos, window)
-    if k.shape[1] <= kv_chunk and q_chunk == 0 and not s_low_precision:
-        # degenerate chunking: the whole KV fits in one chunk, so the
-        # online-softmax scan buys nothing and its backward's per-chunk
-        # probability recompute is pure extra arithmetic — the direct form
-        # is exact attention over the same mask and lets XLA keep p for
-        # the backward (score matrix is <= one chunk wide by construction)
-        return naive_attention(q, k, v, q_pos, k_pos, window)
-    return online_attention(q, k, v, q_pos, k_pos, window=window,
-                            kv_chunk=kv_chunk, q_chunk=q_chunk,
-                            causal_prefix=causal_prefix,
-                            s_low_precision=s_low_precision)
+
+    def _jnp():
+        if k.shape[1] <= kv_chunk and q_chunk == 0 and not s_low_precision:
+            # degenerate chunking: the whole KV fits in one chunk, so the
+            # online-softmax scan buys nothing and its backward's per-chunk
+            # probability recompute is pure extra arithmetic — the direct
+            # form is exact attention over the same mask and lets XLA keep
+            # p for the backward (score matrix is <= one chunk wide)
+            return naive_attention(q, k, v, q_pos, k_pos, window)
+        return online_attention(q, k, v, q_pos, k_pos, window=window,
+                                kv_chunk=kv_chunk, q_chunk=q_chunk,
+                                causal_prefix=causal_prefix,
+                                s_low_precision=s_low_precision)
+
+    if not _kernel_covers(q, k, v, causal_prefix, s_low_precision):
+        return _jnp()
+    from ..kernels.flash_attention import flash_attention_train
+    return flash_attention_train(q, k, v, window=window, ref=_jnp)
 
 
 # ---------------------------------------------------------------------------

@@ -4,7 +4,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels.flash_attention import flash_attention
+from repro.kernels.flash_attention import flash_attention_train
 from repro.kernels.flash_attention.ref import flash_attention_ref
 from repro.kernels.lora_matmul import lora_matmul, lora_matmul_ref
 from repro.kernels.ssd_scan import ssd_scan, ssd_sequential_ref
@@ -117,11 +117,14 @@ def _tuner_calls():
                          lambda: ft.best_decode_block(4, 12, 1, 128, 64)),
         "paged_decode": (ft.clear_paged_cache,
                          lambda: ft.best_paged_block(4, 12, 1, 8, 16, 64)),
+        "flash_train": (ft.clear_train_cache,
+                        lambda: ft.best_train_blocks(15, 12, 12, 512, 512,
+                                                     64)),
     }
 
 
 @pytest.mark.parametrize("tuner", ["lora", "lora_gather", "flash_decode",
-                                   "paged_decode"])
+                                   "paged_decode", "flash_train"])
 def test_tuners_pick_the_same_tiles_on_every_backend(monkeypatch, tuner):
     """One deterministic rule on every backend: a CPU compile rehearsal
     lowers exactly the tiles the chip runs (the tuners are called while
@@ -150,7 +153,10 @@ def test_flash_attention_sweep(B, Sq, Sk, H, KH, D, win, dtype):
                           jnp.float32).astype(dtype)
     v = jax.random.normal(jax.random.key(2), (B, Sk, KH, D),
                           jnp.float32).astype(dtype)
-    o = flash_attention(q, k, v, window=win, bq=32, bk=32)
+    # f32 tile operands (highest precision) for the f32 tolerances
+    with jax.default_matmul_precision("highest"):
+        o = flash_attention_train(q, k, v, window=win, bq=32, bk=32,
+                                  interpret=True)
     oref = flash_attention_ref(q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
                                v.transpose(0, 2, 1, 3),
                                window=win).transpose(0, 2, 1, 3)
@@ -190,7 +196,9 @@ def test_kernels_match_model_twins(key):
     v = jax.random.normal(jax.random.key(2), (B, Sq, KH, D))
     pos = jnp.arange(Sq)
     o_model = online_attention(q, k, v, pos, pos, kv_chunk=16)
-    o_kernel = flash_attention(q, k, v, bq=32, bk=32)
+    with jax.default_matmul_precision("highest"):
+        o_kernel = flash_attention_train(q, k, v, bq=32, bk=32,
+                                         interpret=True)
     np.testing.assert_allclose(np.asarray(o_model), np.asarray(o_kernel),
                                atol=2e-5, rtol=2e-5)
 

@@ -16,7 +16,8 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.kernels.flash_attention import flash_decode, paged_decode
+from repro.kernels.flash_attention import (flash_attention_train,
+                                           flash_decode, paged_decode)
 from repro.kernels.lora_matmul import lora_matmul, lora_matmul_gathered
 
 # GPT2-S (configs/gpt2_s.py): d 768, d_ff 3072, 12 heads of 64, LoRA r 4.
@@ -24,6 +25,14 @@ from repro.kernels.lora_matmul import lora_matmul, lora_matmul_gathered
 # serving decodes 4 slots over a 128-token cache in 16-token pages.
 D_MODEL, D_FF, HEADS, HEAD_DIM, RANK = 768, 3072, 12, 64, 4
 M_TRAIN, SLOTS, MAX_LEN, PAGE = 4 * 512, 4, 128, 16
+# the benchmark cells' pooled server batch K x b at S = 512, f32: GPT2-S
+# (K 5 x b 3, 12 heads of 64) and GPT2-M (5 x 1, 16 heads of 64), where
+# the backward is one kernel; and a longer sequence of several blocks,
+# where it is a dK/dV and a dQ kernel
+ATTN_CELLS = {"gpt2-s": (15, 512, 12, 64), "gpt2-m": (5, 512, 16, 64),
+              "gpt2-s-s2048": (2, 2048, 12, 64)}
+ATTN_BWD = {"gpt2-s": ("attn_bwd",), "gpt2-m": ("attn_bwd",),
+            "gpt2-s-s2048": ("attn_dkv", "attn_dq")}
 
 
 @pytest.fixture(scope="module")
@@ -127,6 +136,66 @@ def test_paged_decode_compiles(one_chip, no_cache, kv_dtype):
     _compile(decode, shapes, one_chip)
 
 
+def _named(text, scope):
+    """Custom calls whose instruction name carries ``scope``."""
+    import re
+    return re.findall(rf"^\s*(?:ROOT )?%?[\w.-]*{scope}[\w.-]* = .* "
+                      r"custom-call\(", text, re.M)
+
+
+@pytest.mark.parametrize("cell", sorted(ATTN_CELLS))
+def test_flash_attention_train_forward_compiles(one_chip, no_cache, cell):
+    shape = ATTN_CELLS[cell]
+
+    def fwd(q, k, v):
+        return flash_attention_train(q, k, v, interpret=False)
+
+    text = _compile(fwd, [(shape, jnp.float32)] * 3, one_chip)
+    assert len(_named(text, "attn_fwd")) == 1
+
+
+@pytest.mark.parametrize("cell", sorted(ATTN_CELLS))
+def test_flash_attention_train_backward_compiles(one_chip, no_cache, cell):
+    shape = ATTN_CELLS[cell]
+
+    def loss(q, k, v, g):
+        return jnp.sum(flash_attention_train(q, k, v, interpret=False) * g)
+
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2)),
+                    [(shape, jnp.float32)] * 4, one_chip)
+    for scope in ("attn_fwd",) + ATTN_BWD[cell]:
+        assert len(_named(text, scope)) == 1, scope
+    for scope in {"attn_bwd", "attn_dkv", "attn_dq"} - set(ATTN_BWD[cell]):
+        assert not _named(text, scope), scope
+
+
+def test_attention_kernels_named_beside_lora_in_a_scanned_grad(one_chip,
+                                                               no_cache):
+    """A gradient through a depth scan of one LoRA projection and the
+    attention custom VJP: each attention kernel's instruction (forward,
+    one-block backward) carries its scope's name, so ``closed_call``
+    counts the LoRA kernels alone (the forward, dX and two rank
+    reductions), as the benchmark's ``lora_roofline.train`` reads them."""
+    L, B, S, H, D = 2, 2, 128, 2, 64
+    d = H * D
+
+    def loss(x, w, a, b):
+        def layer(x, p):
+            h = lora_matmul(x, *p, scale=2.0, interpret=False)
+            o = flash_attention_train(*(h.reshape(B, S, H, D),) * 3,
+                                      interpret=False)
+            return x + o.reshape(B, S, d), None
+        return jnp.sum(jax.lax.scan(layer, x, (w, a, b))[0] ** 2)
+
+    text = _compile(jax.grad(loss, argnums=(0, 2, 3)),
+                    [((B, S, d), jnp.float32), ((L, d, d), jnp.float32),
+                     ((L, RANK, d), jnp.float32), ((L, d, RANK), jnp.float32)],
+                    one_chip)
+    assert len(_named(text, "closed_call")) == 4
+    for scope in ("attn_fwd", "attn_bwd"):
+        assert len(_named(text, scope)) == 1, scope
+
+
 def test_sfl_round_keeps_kernel_names_under_phase_scopes(one_chip, no_cache,
                                                          monkeypatch):
     """The tiny SFL round with the fused kernels forced on: the phase
@@ -134,7 +203,10 @@ def test_sfl_round_keeps_kernel_names_under_phase_scopes(one_chip, no_cache,
     ``closed_call`` (a scope directly around one would rename it, and the
     benchmark counts kernels by that name), and the phases reach the
     compiled fusions' metadata.  Per local step of a 2-layer round split
-    at 1: 2L forward, 2(L-1) dX and 4L rank reductions."""
+    at 1: 2L forward, 2(L-1) dX and 4L rank reductions.  The attention
+    kernels sit in scopes of their own and carry those names: one
+    instruction each for the client's vmapped layer and the server's
+    depth scan."""
     import re
 
     from repro.configs import TrainConfig, get_arch
@@ -167,6 +239,8 @@ def test_sfl_round_keeps_kernel_names_under_phase_scopes(one_chip, no_cache,
     kernels = re.findall(r"^\s*(?:ROOT )?%?closed_call[.\d]* = .* "
                          r"custom-call\(", text, re.M)
     assert len(kernels) == 2 * L + 2 * (L - 1) + 4 * L
+    for scope in ("attn_fwd", "attn_bwd"):
+        assert len(_named(text, scope)) == 2, scope
     fusion_meta = "\n".join(re.findall(r"^\s*(?:ROOT )?%?[\w.-]*fusion[\w.-]* = "
                                        r".*op_name=\"([^\"]+)\"", text, re.M))
     for phase in ("sfl.server_stack", "sfl.client"):
