@@ -18,6 +18,7 @@ from . import (
     llama4_scout_17b_a16e,
     gpt2_s,
     gpt2_m,
+    moonlight_16b_a3b,
 )
 
 # The ten assigned architectures (dry-run / roofline targets).
@@ -37,7 +38,10 @@ ASSIGNED = (
 # Paper's own models (benchmarks of Section VII).
 PAPER_MODELS = (gpt2_s.CONFIG, gpt2_m.CONFIG)
 
-ARCHS = {c.name: c for c in ASSIGNED + PAPER_MODELS}
+# Configurations that train only (latent attention has no decode cache).
+TRAIN_MODELS = (moonlight_16b_a3b.CONFIG,)
+
+ARCHS = {c.name: c for c in ASSIGNED + PAPER_MODELS + TRAIN_MODELS}
 
 
 def get_arch(name: str) -> ArchConfig:
@@ -58,5 +62,6 @@ __all__ = [
     "ArchConfig", "LayerPattern", "ShapeConfig", "TrainConfig", "SystemConfig",
     "DEFAULT_SYSTEM", "channel_gain", "path_loss_db",
     "SHAPES", "TRAIN_4K", "PREFILL_32K", "DECODE_32K", "LONG_500K",
-    "ASSIGNED", "PAPER_MODELS", "ARCHS", "get_arch", "get_shape",
+    "ASSIGNED", "PAPER_MODELS", "TRAIN_MODELS", "ARCHS", "get_arch",
+    "get_shape",
 ]

@@ -12,7 +12,7 @@ import dataclasses
 from dataclasses import dataclass
 from typing import Literal, Optional, Tuple
 
-Mixer = Literal["attention", "mamba"]
+Mixer = Literal["attention", "mla", "mamba"]
 Mlp = Literal["dense", "moe", "none"]
 
 
@@ -40,13 +40,24 @@ class ArchConfig:
     d_ff: int = 3072
     vocab_size: int = 50257
 
-    # depth pattern (len must divide num_layers) ---------------------------
+    # depth pattern (len must divide the layers after the prefix) ----------
     pattern: Tuple[LayerPattern, ...] = (LayerPattern(),)
+    # leading layers outside the repeating pattern (DeepSeek's
+    # ``first_k_dense_replace``): counted in num_layers, run before it
+    prefix: Tuple[LayerPattern, ...] = ()
 
     # attention ------------------------------------------------------------
     attn_window: int = 0                # 0 = full attention
     rope_theta: float = 10_000.0
     pos_emb: Literal["rope", "learned", "none"] = "rope"
+    # multi-head latent attention (mixer "mla", DeepSeek-V2/V3): k and v
+    # come up from a normed latent of kv_lora_rank; each head's q and k
+    # are qk_nope_head_dim plain dims and qk_rope_head_dim rotated ones,
+    # the rotated key shared by every head; v heads are v_head_dim wide
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
 
     # mlp / norm -----------------------------------------------------------
     mlp_kind: Literal["swiglu", "gelu_mlp"] = "swiglu"
@@ -59,6 +70,19 @@ class ArchConfig:
     experts_per_token: int = 0
     shared_expert: bool = False         # llama4-style always-on expert
     router_aux_coef: float = 0.01       # load-balance loss weight
+    moe_d_ff: int = 0                   # routed expert width (0: d_ff)
+    shared_d_ff: int = 0                # shared expert width (0: d_ff)
+    # "capacity": softmax top-k, GShard capacity dispatch (drops tokens);
+    # "dropless": DeepSeek-V3's router (sigmoid scores, a selection-only
+    # correction bias), every (token, choice) row reaches its expert
+    # (grouped matmuls)
+    moe_dispatch: Literal["capacity", "dropless"] = "capacity"
+    routed_scale: float = 1.0           # on the normalised gate weights
+    # the experts this chip holds, ids [first_expert, first_expert +
+    # experts_held) of num_experts (0 = all): the layer routes over all
+    # of them and computes its own experts' part of the result
+    experts_held: int = 0
+    first_expert: int = 0
 
     # Mamba2 / SSD -----------------------------------------------------------
     ssm_state: int = 0
@@ -81,10 +105,11 @@ class ArchConfig:
     def __post_init__(self) -> None:
         if self.head_dim == 0 and self.num_heads:
             object.__setattr__(self, "head_dim", self.d_model // max(self.num_heads, 1))
-        if self.num_layers % len(self.pattern) != 0:
+        if (self.num_layers - len(self.prefix)) % len(self.pattern) != 0:
             raise ValueError(
                 f"{self.name}: pattern length {len(self.pattern)} must divide "
-                f"num_layers {self.num_layers}"
+                f"the {self.num_layers - len(self.prefix)} layers after the "
+                f"prefix"
             )
         if self.num_heads and self.num_kv_heads and self.num_heads % self.num_kv_heads:
             raise ValueError(f"{self.name}: num_heads % num_kv_heads != 0")
@@ -92,7 +117,15 @@ class ArchConfig:
     # derived ------------------------------------------------------------
     @property
     def pattern_repeats(self) -> int:
-        return self.num_layers // len(self.pattern)
+        return (self.num_layers - len(self.prefix)) // len(self.pattern)
+
+    @property
+    def held_experts(self) -> int:
+        return self.experts_held or self.num_experts
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
 
     @property
     def d_inner(self) -> int:
@@ -106,7 +139,9 @@ class ArchConfig:
     @property
     def layer_kinds(self) -> Tuple[LayerPattern, ...]:
         """Per-layer (mixer, mlp) for all `num_layers` layers."""
-        return tuple(self.pattern[i % len(self.pattern)] for i in range(self.num_layers))
+        body = self.num_layers - len(self.prefix)
+        return self.prefix + tuple(self.pattern[i % len(self.pattern)]
+                                   for i in range(body))
 
     @property
     def has_attention(self) -> bool:
@@ -122,8 +157,8 @@ class ArchConfig:
                 max_experts: int = 4, vocab: int = 512) -> "ArchConfig":
         """A tiny same-family variant for CPU smoke tests."""
         pat = self.pattern
-        if num_layers % len(pat) != 0:
-            num_layers = len(pat)
+        if (num_layers - len(self.prefix)) % len(pat) != 0:
+            num_layers = len(self.prefix) + len(pat)
         num_heads = min(self.num_heads, 4) or 0
         num_kv = min(self.num_kv_heads, num_heads) or 0
         if num_heads and num_kv and num_heads % num_kv:
@@ -140,6 +175,14 @@ class ArchConfig:
             vocab_size=vocab,
             num_experts=min(self.num_experts, max_experts),
             experts_per_token=min(self.experts_per_token, 2),
+            experts_held=0,
+            first_expert=0,
+            moe_d_ff=self.moe_d_ff and d_model,
+            shared_d_ff=self.shared_d_ff and d_model,
+            kv_lora_rank=self.kv_lora_rank and d_model // 4,
+            qk_nope_head_dim=self.qk_nope_head_dim and 32,
+            qk_rope_head_dim=self.qk_rope_head_dim and 16,
+            v_head_dim=self.v_head_dim and 32,
             ssm_state=min(self.ssm_state, 16) if self.ssm_state else 0,
             ssm_head_dim=32 if self.ssm_state else self.ssm_head_dim,
             ssm_chunk=32,

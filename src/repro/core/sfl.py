@@ -113,6 +113,20 @@ def _ce_terms(logits: jax.Array, labels: jax.Array):
     return jnp.sum((logz - gold) * mask), mask.sum()
 
 
+def _concat_rows(parts):
+    """Per-layer expert row counts of consecutive stack slices, None where
+    no slice has a dropless MoE layer."""
+    parts = [r for r in parts if r is not None]
+    return jnp.concatenate(parts) if parts else None
+
+
+def _split_rows(out):
+    """A client pass's (acts, aux, rows) as ((acts, aux), rows) for
+    ``jax.vjp(has_aux=True)``: the counts carry no gradient."""
+    acts, aux, rows = out
+    return (acts, aux), rows
+
+
 @jax.tree_util.register_dataclass
 @dataclass
 class SflState:
@@ -345,6 +359,10 @@ class SflLLM:
         # Every jitted call takes ``self.base`` as an ARGUMENT: closed over,
         # the weights would be baked into the executable as constants (a
         # second device copy of the model and a model-sized compile).
+        if cfg.prefix and self.hetero:
+            raise NotImplementedError(
+                "per-client splits and ranks need a config without leading "
+                "layers outside its pattern")
         base = {
             "client": {
                 "embed": params["embed"],
@@ -358,6 +376,8 @@ class SflLLM:
                 "final_norm": params["final_norm"],
             },
         }
+        if cfg.prefix:          # the leading layers stay whole on clients
+            base["client"]["prefix"] = params["prefix"]
         if mesh is not None:
             from ..sharding.specs import replicated_shardings
             base = jax.device_put(base, replicated_shardings(base, mesh))
@@ -495,8 +515,11 @@ class SflLLM:
                     raise ValueError(
                         f"template rank {r} != max client rank {self.r_max}"
                         " — build the template with SflLLM.init_lora")
-        lc = jax.tree.map(lambda v: v[:self.rep_max], lora_template)
-        ls = jax.tree.map(lambda v: v[self.rep_min:], lora_template)
+        layers = lora_template["layers"] if self.cfg.prefix else lora_template
+        lc = jax.tree.map(lambda v: v[:self.rep_max], layers)
+        ls = jax.tree.map(lambda v: v[self.rep_min:], layers)
+        if self.cfg.prefix:
+            lc = {"prefix": lora_template["prefix"], "layers": lc}
         K = self.tc.num_clients
         lc_k = jax.tree.map(lambda v: jnp.broadcast_to(v, (K,) + v.shape).copy(), lc)
         if self._client_masks is not None:
@@ -539,12 +562,21 @@ class SflLLM:
         x = embed(cfg, cbase["embed"], tokens, positions[-tokens.shape[1]:])
         if frontend_emb is not None:
             x = jnp.concatenate([frontend_emb.astype(x.dtype), x], axis=1)
-        x, _, aux = stack_mod.apply_stack(
+        rows = []
+        if cfg.prefix:
+            x, r, aux = stack_mod.apply_stack(
+                cfg, cbase["prefix"], x, positions=positions,
+                lora=lora_c["prefix"], rt=rt, mode="train",
+                lora_scale=lora_scale, pattern=cfg.prefix)
+            rows.append(r)
+            lora_c = lora_c["layers"]
+        x, r, a = stack_mod.apply_stack(
             cfg, cbase["layers"], x, positions=positions,
             lora=lora_c, rt=rt, mode="train",
             rep_gate=(None, rep_hi) if rep_hi is not None else None,
             lora_scale=lora_scale)
-        return x, aux
+        rows.append(r)
+        return x, (aux + a if cfg.prefix else a), _concat_rows(rows)
 
     def _server_loss(self, lora_s, acts, labels, sbase, rep_lo=None):
         """Pooled loss on the main server.  acts: (K, b, S, d); ``sbase``
@@ -560,30 +592,33 @@ class SflLLM:
         (Pallas kernels cannot be partitioned automatically), and the
         cross-entropy partial sums meet before the divide."""
         if self.mesh is None:
-            num, den, aux = self._server_terms(lora_s, acts, labels, sbase,
-                                               rep_lo)
+            num, den, aux, rows = self._server_terms(lora_s, acts, labels,
+                                                     sbase, rep_lo)
         else:
             def body(lora_s, acts, labels, sbase, rep_lo):
-                return tuple(t[None] for t in self._server_terms(
-                    lora_s, acts, labels, sbase, rep_lo))
+                return tuple(None if t is None else t[None]
+                             for t in self._server_terms(
+                                 lora_s, acts, labels, sbase, rep_lo))
 
             C = P(CLIENT_AXIS)
-            num, den, aux = jax.shard_map(
+            num, den, aux, rows = jax.shard_map(
                 body, mesh=self.mesh, in_specs=(P(), C, C, P(), C),
                 out_specs=C, check_vma=False)(lora_s, acts, labels, sbase,
                                               rep_lo)
             num, den, aux = num.sum(), den.sum(), aux.mean()
+            rows = None if rows is None else rows.sum(0)
         loss = num / jnp.maximum(den, 1.0)
-        return loss + self.aux_coef * aux, loss
+        return loss + self.aux_coef * aux, (loss, rows)
 
     def _server_terms(self, lora_s, acts, labels, sbase, rep_lo):
-        """Server FP over ``acts``: (CE sum, labelled-token count, aux)."""
+        """Server FP over ``acts``: (CE sum, labelled-token count, aux,
+        rows routed to each held expert per dropless MoE layer or None)."""
         cfg, rt = self.cfg, self.rt
         K, b, S, d = acts.shape
         x = acts.reshape(K * b, S, d)
         positions = jnp.arange(S, dtype=jnp.int32)
         with jax.named_scope("sfl.server_stack"):
-            x, _, aux = stack_mod.apply_stack(
+            x, rows, aux = stack_mod.apply_stack(
                 cfg, sbase["layers"], x, positions=positions,
                 lora=lora_s, rt=rt, mode="train",
                 rep_gate=(rep_lo, None) if rep_lo is not None else None,
@@ -596,7 +631,7 @@ class SflLLM:
             if F > 0:
                 logits = logits[:, F:]
             num, den = _ce_terms(logits, lbl)
-        return num, den, aux
+        return num, den, aux, rows
 
     # ------------------------------------------------------------------
     def _local_step(self, base, state: SflState,
@@ -692,7 +727,8 @@ class SflLLM:
             with jax.named_scope("sfl.client"):
                 return fwd_k(ls, *client_args, base["client"])
 
-        (acts, client_aux), client_vjp = jax.vjp(fwd, state.lora_client)
+        (acts, client_aux), client_vjp, client_rows = jax.vjp(
+            lambda ls: _split_rows(fwd(ls)), state.lora_client, has_aux=True)
 
         # boundary quantization (repro.precision): the uploaded payload is
         # the (de)quantized activation — applied OUTSIDE the client vjp,
@@ -722,9 +758,8 @@ class SflLLM:
             rep_lo = jnp.repeat(rep_hi - self.rep_min, b)  # (K*b,)
         grad_fn = jax.value_and_grad(self._server_loss, argnums=(0, 1),
                                      has_aux=True)
-        (total, loss), (g_server, g_acts) = grad_fn(state.lora_server, acts,
-                                                    labels, base["server"],
-                                                    rep_lo)
+        (total, (loss, server_rows)), (g_server, g_acts) = grad_fn(
+            state.lora_server, acts, labels, base["server"], rep_lo)
 
         # (e) download dL/ds_k; (f) client-side BP --------------------------
         # the downloaded gradient is quantized the same way the uploaded
@@ -778,7 +813,13 @@ class SflLLM:
                 err_act=new_err_act,
                 err_grad=new_err_grad,
             )
-        return new, {"loss": loss, "total": total}
+        metrics = {"loss": loss, "total": total}
+        rows = _concat_rows([None if client_rows is None
+                             else client_rows.sum(0), server_rows])
+        if rows is not None:
+            # rows routed to each held expert, per dropless MoE layer
+            metrics["expert_rows"] = rows
+        return new, metrics
 
     # ------------------------------------------------------------------
     def _aggregate(self, state: SflState, weights: jax.Array) -> SflState:
@@ -1115,17 +1156,17 @@ class SflLLM:
         scales = self._scale_k
         scale0 = scales[0] if isinstance(scales, tuple) else scales
         rep_hi0 = jnp.int32(self.rep_k[0]) if self.hetero_split else None
-        acts, _ = self._client_forward(lora_c0, batch["tokens"],
-                                       batch.get("frontend_emb"),
-                                       base["client"], rep_hi=rep_hi0,
-                                       lora_scale=scale0)
+        acts, _, _ = self._client_forward(lora_c0, batch["tokens"],
+                                          batch.get("frontend_emb"),
+                                          base["client"], rep_hi=rep_hi0,
+                                          lora_scale=scale0)
         rep_lo = None
         if self.hetero_split:
             b = batch["tokens"].shape[0]
             rep_lo = jnp.full((b,), self.rep_k[0] - self.rep_min, jnp.int32)
-        num, den, _ = self._server_terms(state.lora_server, acts[None],
-                                         batch["labels"][None],
-                                         base["server"], rep_lo)
+        num, den, _, _ = self._server_terms(state.lora_server, acts[None],
+                                            batch["labels"][None],
+                                            base["server"], rep_lo)
         return num / jnp.maximum(den, 1.0)
 
     def eval_loss(self, state, batch):

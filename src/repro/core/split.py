@@ -18,16 +18,21 @@ def valid_splits(cfg: ArchConfig) -> List[int]:
 
     0 is excluded (pure-FL degenerates the paper's setting: the client must
     hold at least the embedding + first block to keep raw data private);
-    num_layers is excluded (the main server must hold the head)."""
-    P = len(cfg.pattern)
-    return [r * P for r in range(1, cfg.pattern_repeats)]
+    num_layers is excluded (the main server must hold the head).  Leading
+    layers outside the pattern (``cfg.prefix``) stay whole on the client:
+    their end is the first legal split, and the pattern's boundaries
+    after it the others."""
+    P, F = len(cfg.pattern), len(cfg.prefix)
+    return [F + r * P for r in range(0 if F else 1, cfg.pattern_repeats)]
 
 
 def layers_to_reps(cfg: ArchConfig, ell_c: int) -> int:
-    P = len(cfg.pattern)
-    if ell_c % P:
-        raise ValueError(f"split {ell_c} not on a pattern boundary (P={P})")
-    return ell_c // P
+    """Pattern repeats on the client for a split after ``ell_c`` layers."""
+    P, F = len(cfg.pattern), len(cfg.prefix)
+    if ell_c < F or (ell_c - F) % P:
+        raise ValueError(f"split {ell_c} not on a pattern boundary (P={P}, "
+                         f"{F} leading layers)")
+    return (ell_c - F) // P
 
 
 def mu_vector(cfg: ArchConfig, ell_c: int) -> Tuple[int, ...]:

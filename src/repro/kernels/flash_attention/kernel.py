@@ -42,6 +42,9 @@ rule (``tune.best_train_blocks``) keeps head groups small, and each
 replayed after (``_traced``): a round's trace meets each kernel more than
 once (a custom VJP's primal and its forward rule).
 
+q and k heads are ``head_dim`` wide and v heads ``v_dim`` (default the
+same; latent attention's differ): the output, dO and dV take v's width.
+
 ``mxu_dtype`` is the operand dtype of the tile matmuls: q (scaled by
 D^-1/2 first), k, v, p, dO and dS are cast to it in VMEM; accumulation and
 the softmax statistics stay f32.
@@ -158,7 +161,7 @@ def _row_to_col(row):
 # ---------------------------------------------------------------------------
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref,
-                vt_ref, *, D: int, scale: float, bq: int, bk: int,
+                vt_ref, *, D: int, Dv: int, scale: float, bq: int, bk: int,
                 k_steps: int, q_offset: int, window: int, seq_k: int,
                 group: int, fast: bool, padded: bool, mxu_dtype):
     """Works on transposed (k, q) tiles: the softmax statistics reduce over
@@ -180,7 +183,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref,
     def tile(kind):
         q_lo, k_lo = q_offset + qi * bq, kj * bk
         for c in range(vt_ref.shape[0]):
-            vt_ref[c] = jnp.transpose(v_ref[0, :, c * D:(c + 1) * D].astype(
+            vt_ref[c] = jnp.transpose(v_ref[0, :, c * Dv:(c + 1) * Dv].astype(
                 jnp.float32)).astype(vt_ref.dtype)
         for h in range(heads):
             c = h // group
@@ -207,7 +210,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref,
                     alpha = jnp.exp(m - m_new)
                     l = l * alpha + jnp.sum(p_t, axis=0, keepdims=True)
                     acc = acc * alpha + _dot(vt_ref[c, :, keys], p_t, _NN,
-                                             mxu_dtype)        # (D, tq)
+                                             mxu_dtype)        # (Dv, tq)
                     m = m_new
                 m_ref[h, :, cols], l_ref[h, :, cols] = m, l
                 acc_ref[h, :, cols] = acc
@@ -219,7 +222,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref,
     def _finish():
         for h in range(heads):
             l = jnp.maximum(l_ref[h], 1e-30)                # (1, bq)
-            o_ref[0, :, h * D:(h + 1) * D] = jnp.transpose(
+            o_ref[0, :, h * Dv:(h + 1) * Dv] = jnp.transpose(
                 acc_ref[h] / l).astype(o_ref.dtype)
             lse_ref[0, h] = m_ref[h] + jnp.log(l)
 
@@ -258,16 +261,17 @@ def _geometry(q, k, D, bq, bk, seq_k, q_offset, window):
                 padded=seq_k < Sk)
 
 
-def flash_attention_kernel(q, k, v, *, head_dim: int, window: int = 0,
-                           seq_k: int = 0, q_offset: int = -1,
-                           bq: int = 256, bk: int = 256, kv_heads: int = 1,
-                           mxu_dtype=None, interpret: bool = False):
-    """q: (B, Sq, H*D); k/v: (B, Sk, KH*D) with D = ``head_dim``, sequence
-    dims divisible by the blocks (ops.py pads).  Causal; ``q_offset`` is
-    the absolute position of q row 0 (default: aligned at the TRUE
-    sequence end, seq_k - Sq).  Returns (o (B, Sq, H*D), lse f32
-    (B, H, 1, Sq))."""
-    D = head_dim
+def flash_attention_kernel(q, k, v, *, head_dim: int, v_dim: int = 0,
+                           window: int = 0, seq_k: int = 0,
+                           q_offset: int = -1, bq: int = 256, bk: int = 256,
+                           kv_heads: int = 1, mxu_dtype=None,
+                           interpret: bool = False):
+    """q: (B, Sq, H*D); k: (B, Sk, KH*D); v: (B, Sk, KH*Dv) with D =
+    ``head_dim`` and Dv = ``v_dim`` (default D), sequence dims divisible by
+    the blocks (ops.py pads).  Causal; ``q_offset`` is the absolute
+    position of q row 0 (default: aligned at the TRUE sequence end,
+    seq_k - Sq).  Returns (o (B, Sq, H*Dv), lse f32 (B, H, 1, Sq))."""
+    D, Dv = head_dim, v_dim or head_dim
     g = _geometry(q, k, D, bq, bk, seq_k, q_offset, window)
     B, bq, bk, G = g["B"], g["bq"], g["bk"], g["G"]
     hb = kv_heads * G
@@ -275,30 +279,33 @@ def flash_attention_kernel(q, k, v, *, head_dim: int, window: int = 0,
     kvb = functools.partial(_kv_block, bq=bq, bk=bk, q_offset=g["q_offset"],
                             window=window)
     q_spec = pl.BlockSpec((1, bq, hb * D), lambda b, h, i, j: (b, i, h))
+    o_spec = pl.BlockSpec((1, bq, hb * Dv), lambda b, h, i, j: (b, i, h))
     kv_spec = pl.BlockSpec((1, bk, kv_heads * D),
                            lambda b, h, i, j: (b, kvb(i, j), h))
+    v_spec = pl.BlockSpec((1, bk, kv_heads * Dv),
+                          lambda b, h, i, j: (b, kvb(i, j), h))
     make = lambda: pl.pallas_call(
-        functools.partial(_fwd_kernel, D=D, scale=D ** -0.5, bq=bq,
+        functools.partial(_fwd_kernel, D=D, Dv=Dv, scale=D ** -0.5, bq=bq,
                           bk=bk, k_steps=grid[3], q_offset=g["q_offset"],
                           window=window, seq_k=g["seq_k"], group=G,
                           fast=g["fast"], padded=g["padded"],
                           mxu_dtype=mxu_dtype or q.dtype),
         grid=grid,
-        in_specs=[q_spec, kv_spec, kv_spec],
-        out_specs=[q_spec,
+        in_specs=[q_spec, kv_spec, v_spec],
+        out_specs=[o_spec,
                    pl.BlockSpec((1, hb, 1, bq),
                                 lambda b, h, i, j: (b, h, 0, i))],
-        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
+        out_shape=[jax.ShapeDtypeStruct((B, g["Sq"], g["H"] * Dv), q.dtype),
                    jax.ShapeDtypeStruct((B, g["H"], 1, g["Sq"]),
                                         jnp.float32)],
         scratch_shapes=[pltpu.VMEM((hb, 1, bq), jnp.float32),
                         pltpu.VMEM((hb, 1, bq), jnp.float32),
-                        pltpu.VMEM((hb, D, bq), jnp.float32),
-                        pltpu.VMEM((kv_heads, D, bk),
+                        pltpu.VMEM((hb, Dv, bq), jnp.float32),
+                        pltpu.VMEM((kv_heads, Dv, bk),
                                    mxu_dtype or q.dtype)],
         interpret=interpret,
     )
-    config = (D, window, g["seq_k"], g["q_offset"], bq, bk, kv_heads,
+    config = (D, Dv, window, g["seq_k"], g["q_offset"], bq, bk, kv_heads,
               jnp.dtype(mxu_dtype or q.dtype).name, interpret)
     return _traced("attn_fwd", config, make, q, k, v)
 
@@ -308,7 +315,7 @@ def flash_attention_kernel(q, k, v, *, head_dim: int, window: int = 0,
 # ---------------------------------------------------------------------------
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, *refs,
-                D: int, scale: float, bq: int, bk: int, q_steps: int,
+                D: int, Dv: int, scale: float, bq: int, bk: int, q_steps: int,
                 q_offset: int, window: int, seq_k: int, group: int,
                 fast: bool, padded: bool, fuse_dq: bool, mxu_dtype):
     """dK and dV of one KV block over the q blocks.  With ``fuse_dq`` (one
@@ -339,7 +346,8 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, *refs,
                     jnp.float32)).astype(kt_ref.dtype)
             for b in range(bk // tk):
                 keys = slice(b * tk, (b + 1) * tk)
-                k, v = k_ref[0, keys, lanes], v_ref[0, keys, lanes]
+                k = k_ref[0, keys, lanes]
+                v = v_ref[0, keys, c * Dv:(c + 1) * Dv]
                 dk, dv = dk_acc[c, keys], dv_acc[c, keys]
                 for h in range(c * group, (c + 1) * group):
                     for a in range(bq // tq):
@@ -348,7 +356,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, *refs,
                         rows = slice(a * tq, (a + 1) * tq)
                         q = (q_ref[0, rows, h * D:(h + 1) * D].astype(
                             jnp.float32) * scale).astype(mxu_dtype)
-                        do = do_ref[0, rows, h * D:(h + 1) * D]
+                        do = do_ref[0, rows, h * Dv:(h + 1) * Dv]
                         s_t = _dot(k, q, _NT, mxu_dtype)       # (tk, tq)
                         mask = _sub_mask(kind, a, b, q_lo, k_lo, tq, tk, 1,
                                          window, seq_k, padded)
@@ -373,7 +381,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, *refs,
     def _finish():
         for c in range(kv_heads):
             dk_ref[0, :, c * D:(c + 1) * D] = dk_acc[c].astype(dk_ref.dtype)
-            dv_ref[0, :, c * D:(c + 1) * D] = dv_acc[c].astype(dv_ref.dtype)
+            dv_ref[0, :, c * Dv:(c + 1) * Dv] = dv_acc[c].astype(dv_ref.dtype)
         if fuse_dq:
             for h in range(dqt_acc.shape[0]):
                 dq_ref[0, :, h * D:(h + 1) * D] = jnp.transpose(
@@ -381,7 +389,8 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, *refs,
 
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, dq_ref, dq_acc,
-               *, D: int, scale: float, bq: int, bk: int, k_steps: int,
+               *, D: int, Dv: int, scale: float, bq: int, bk: int,
+               k_steps: int,
                q_offset: int, window: int, seq_k: int, group: int,
                fast: bool, padded: bool, mxu_dtype):
     qi = pl.program_id(2)
@@ -401,7 +410,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, dq_ref, dq_acc,
                 rows = slice(a * tq, (a + 1) * tq)
                 q = (q_ref[0, rows, h * D:(h + 1) * D].astype(jnp.float32)
                      * scale).astype(mxu_dtype)
-                do = do_ref[0, rows, h * D:(h + 1) * D]
+                do = do_ref[0, rows, h * Dv:(h + 1) * Dv]
                 lse = _row_to_col(lse_ref[0, h, :, rows])[:, :1]
                 di = _row_to_col(di_ref[0, h, :, rows])[:, :1]
                 dq = dq_acc[h, rows]
@@ -418,7 +427,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, dq_ref, dq_acc,
                     p = jnp.exp(s - lse)
                     if kind == "masked":
                         p = jnp.where(mask, p, 0.0)
-                    ds = p * (_dot(do, v_ref[0, keys, c * D:(c + 1) * D],
+                    ds = p * (_dot(do, v_ref[0, keys, c * Dv:(c + 1) * Dv],
                                    _NT, mxu_dtype) - di)
                     dq = dq + _dot(ds, k, _NN, mxu_dtype)
                 dq_acc[h, rows] = dq
@@ -434,19 +443,22 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, di_ref, dq_ref, dq_acc,
 
 
 def flash_attention_bwd_kernels(q, k, v, do, lse, delta, *, head_dim: int,
-                                window: int = 0, seq_k: int = 0,
-                                q_offset: int = -1, bq: int = 256,
-                                bk: int = 256, kv_heads: int = 1,
-                                mxu_dtype=None, interpret: bool = False):
-    """dq, dk, dv of ``flash_attention_kernel``.  q/do: (B, Sq, H*D); k/v:
-    (B, Sk, KH*D); lse and delta = rowsum(do * o) per head: f32
-    (B, H, 1, Sq).  Blocks and masking as the forward."""
-    D = head_dim
+                                v_dim: int = 0, window: int = 0,
+                                seq_k: int = 0, q_offset: int = -1,
+                                bq: int = 256, bk: int = 256,
+                                kv_heads: int = 1, mxu_dtype=None,
+                                interpret: bool = False):
+    """dq, dk, dv of ``flash_attention_kernel``.  q: (B, Sq, H*D), do:
+    (B, Sq, H*Dv); k: (B, Sk, KH*D), v: (B, Sk, KH*Dv); lse and delta =
+    rowsum(do * o) per head: f32 (B, H, 1, Sq).  Blocks and masking as
+    the forward."""
+    D, Dv = head_dim, v_dim or head_dim
     g = _geometry(q, k, D, bq, bk, seq_k, q_offset, window)
     B, bq, bk, G = g["B"], g["bq"], g["bk"], g["G"]
     hb = kv_heads * G
     nq, nk = g["Sq"] // bq, g["Sk"] // bk
-    common = dict(D=D, scale=D ** -0.5, bq=bq, bk=bk, q_offset=g["q_offset"],
+    common = dict(D=D, Dv=Dv, scale=D ** -0.5, bq=bq, bk=bk,
+                  q_offset=g["q_offset"],
                   window=window, seq_k=g["seq_k"], group=G, fast=g["fast"],
                   padded=g["padded"], mxu_dtype=mxu_dtype or q.dtype)
     heads = g["KH"] // kv_heads
@@ -455,20 +467,24 @@ def flash_attention_bwd_kernels(q, k, v, do, lse, delta, *, head_dim: int,
                            q_steps=nq, window=window)
     q_spec = pl.BlockSpec((1, bq, hb * D),
                           lambda b, h, j, i: (b, qb(j, i), h))
+    do_spec = pl.BlockSpec((1, bq, hb * Dv),
+                           lambda b, h, j, i: (b, qb(j, i), h))
     row_spec = pl.BlockSpec((1, hb, 1, bq),
                             lambda b, h, j, i: (b, h, 0, qb(j, i)))
     kv_spec = pl.BlockSpec((1, bk, kv_heads * D),
                            lambda b, h, j, i: (b, j, h))
+    v_spec = pl.BlockSpec((1, bk, kv_heads * Dv),
+                          lambda b, h, j, i: (b, j, h))
     fuse_dq = nq == 1 and nk == 1
     outs = [jax.ShapeDtypeStruct(k.shape, k.dtype),
             jax.ShapeDtypeStruct(v.shape, v.dtype)]
     scratch = [pltpu.VMEM((kv_heads, bk, D), jnp.float32),
-               pltpu.VMEM((kv_heads, bk, D), jnp.float32)]
+               pltpu.VMEM((kv_heads, bk, Dv), jnp.float32)]
     if fuse_dq:
         outs.append(jax.ShapeDtypeStruct(q.shape, q.dtype))
         scratch += [pltpu.VMEM((hb, D, bq), jnp.float32),
                     pltpu.VMEM((D, bk), common["mxu_dtype"])]
-    config = (D, window, g["seq_k"], g["q_offset"], bq, bk, kv_heads,
+    config = (D, Dv, window, g["seq_k"], g["q_offset"], bq, bk, kv_heads,
               jnp.dtype(common["mxu_dtype"]).name, interpret)
     grads = _traced(
         "attn_bwd" if fuse_dq else "attn_dkv", config,
@@ -476,8 +492,8 @@ def flash_attention_bwd_kernels(q, k, v, do, lse, delta, *, head_dim: int,
             functools.partial(_dkv_kernel, q_steps=nq, fuse_dq=fuse_dq,
                               **common),
             grid=(B, heads, nk, nq),
-            in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
-            out_specs=[kv_spec, kv_spec] + [q_spec] * fuse_dq,
+            in_specs=[q_spec, kv_spec, v_spec, do_spec, row_spec, row_spec],
+            out_specs=[kv_spec, v_spec] + [q_spec] * fuse_dq,
             out_shape=outs,
             scratch_shapes=scratch,
             interpret=interpret),
@@ -490,15 +506,18 @@ def flash_attention_bwd_kernels(q, k, v, do, lse, delta, *, head_dim: int,
     kvb = functools.partial(_kv_block, bq=bq, bk=bk, q_offset=g["q_offset"],
                             window=window)
     q_spec = pl.BlockSpec((1, bq, hb * D), lambda b, h, i, j: (b, i, h))
+    do_spec = pl.BlockSpec((1, bq, hb * Dv), lambda b, h, i, j: (b, i, h))
     row_spec = pl.BlockSpec((1, hb, 1, bq), lambda b, h, i, j: (b, h, 0, i))
     kv_spec = pl.BlockSpec((1, bk, kv_heads * D),
                            lambda b, h, i, j: (b, kvb(i, j), h))
+    v_spec = pl.BlockSpec((1, bk, kv_heads * Dv),
+                          lambda b, h, i, j: (b, kvb(i, j), h))
     dq, = _traced(
         "attn_dq", config,
         lambda: pl.pallas_call(
             functools.partial(_dq_kernel, k_steps=nk, **common),
             grid=(B, heads, nq, nk),
-            in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
+            in_specs=[q_spec, kv_spec, v_spec, do_spec, row_spec, row_spec],
             out_specs=q_spec,
             out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
             scratch_shapes=[pltpu.VMEM((hb, bq, D), jnp.float32)],

@@ -35,6 +35,7 @@ def _oracle(q, k, v, window: int):
 class _TrainCfg(NamedTuple):
     """Static kernel config: the custom VJP's nondiff argument."""
     head_dim: int
+    v_dim: int
     window: int
     seq_k: int
     q_offset: int
@@ -64,7 +65,7 @@ def _flash_train_bwd(cfg: _TrainCfg, res, do):
     q, k, v, o, lse = res
     B, S, HD = q.shape
     delta = jnp.sum((o.astype(jnp.float32) * do.astype(jnp.float32)).reshape(
-        B, S, HD // cfg.head_dim, cfg.head_dim), axis=-1)
+        B, S, HD // cfg.head_dim, cfg.v_dim), axis=-1)
     return flash_attention_bwd_kernels(q, k, v, do, lse,
                                        delta.transpose(0, 2, 1)[:, :, None],
                                        **cfg.kw())
@@ -92,8 +93,9 @@ def flash_attention_train(q, k, v, *, window: int = 0,
                           use_kernel: "bool | None" = None):
     """Differentiable causal GQA attention for training and prefill.
 
-    q: (B, Sq, H, D); k/v: (B, Sk, KH, D) — the model layout — with q
-    aligned at the end of k (q row r at position Sk - Sq + r).  On the
+    q: (B, Sq, H, D); k: (B, Sk, KH, D); v: (B, Sk, KH, Dv) — the model
+    layout, Dv = D but for latent attention — with q aligned at the end of
+    k (q row r at position Sk - Sq + r).  On the
     kernel path the forward and both backward passes are Pallas kernels
     behind one custom VJP, on the model layout with the heads merged (no
     transpose): no (Sq, Sk) tensor reaches HBM, and the backward keeps q,
@@ -106,23 +108,23 @@ def flash_attention_train(q, k, v, *, window: int = 0,
     ``tune.best_train_blocks``; the tile matmuls take ``mxu_dtype_for``
     operands."""
     B, Sq, H, D = q.shape
-    Sk, KH = k.shape[1], k.shape[2]
+    Sk, KH, Dv = k.shape[1], k.shape[2], v.shape[-1]
 
     def _ref():
         return ref() if ref is not None else _oracle(q, k, v, window)
 
     def _kern(interp: bool):
-        tq, tk, tkv = best_train_blocks(B, H, KH, Sq, Sk, D, q.dtype)
+        tq, tk, tkv = best_train_blocks(B, H, KH, Sq, Sk, D, q.dtype, Dv=Dv)
         tq, tk, tkv = bq or tq, bk or tk, kv_heads or tkv
         pq, pk = (-Sq) % tq, (-Sk) % tk
-        cfg = _TrainCfg(int(D), int(window), int(Sk), max(Sk - Sq, 0),
-                        int(tq), int(tk), int(tkv),
+        cfg = _TrainCfg(int(D), int(Dv), int(window), int(Sk),
+                        max(Sk - Sq, 0), int(tq), int(tk), int(tkv),
                         mxu_dtype_for(q.dtype).name,
                         bool(interp))
         o = _flash_train(cfg, _pad_seq(q.reshape(B, Sq, H * D), pq),
                          _pad_seq(k.reshape(B, Sk, KH * D), pk),
-                         _pad_seq(v.reshape(B, Sk, KH * D), pk))
-        return o[:, :Sq].reshape(B, Sq, H, D)
+                         _pad_seq(v.reshape(B, Sk, KH * Dv), pk))
+        return o[:, :Sq].reshape(B, Sq, H, Dv)
 
     return backend.dispatch("flash_attention_train", kernel=_kern, ref=_ref,
                             interpret=interpret, use_kernel=use_kernel)

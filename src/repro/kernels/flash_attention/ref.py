@@ -122,11 +122,11 @@ def _scores(q, k, mxu_dtype, window):
 
 
 def flash_attention_train_ref(q, k, v, *, window: int = 0, mxu_dtype=None):
-    """(o, lse) under the training kernels' contract: q (B, H, Sq, D), k/v
-    (B, KH, Sk, D), causal with q aligned at the end of k; the score and
-    PV matmuls take ``mxu_dtype`` operands (default: the input dtype; q
-    scaled by D^-1/2 before its cast) with f32 accumulation, softmax
-    statistics in f32.  lse: f32 (B, H, Sq)."""
+    """(o, lse) under the training kernels' contract: q (B, H, Sq, D), k
+    (B, KH, Sk, D), v (B, KH, Sk, Dv), causal with q aligned at the end of
+    k; the score and PV matmuls take ``mxu_dtype`` operands (default: the
+    input dtype; q scaled by D^-1/2 before its cast) with f32
+    accumulation, softmax statistics in f32.  lse: f32 (B, H, Sq)."""
     mxu_dtype = mxu_dtype or q.dtype
     H, KH = q.shape[1], k.shape[1]
     s, mask = _scores(q, k, mxu_dtype, window)
@@ -160,6 +160,6 @@ def flash_attention_train_bwd_ref(q, k, v, o, lse, do, *, window: int = 0,
     ds = p * (dp - delta[..., None])
     dq = jnp.einsum("bhqk,bhkd->bhqd", c(ds), c(kr), **f32) * D ** -0.5
     dk = jnp.einsum("bhqk,bhqd->bhkd", c(ds), _scaled_q(q, mxu_dtype), **f32)
-    group = lambda t: t.reshape(B, KH, G, Sk, D).sum(2)
+    group = lambda t: t.reshape(B, KH, G, Sk, t.shape[-1]).sum(2)
     return (dq.astype(q.dtype), group(dk).astype(k.dtype),
             group(dv).astype(v.dtype))

@@ -110,8 +110,8 @@ def best_paged_block(B: int, KH: int, G: int, MP: int, PS: int, D: int,
 
 # -- training attention: (bq, bk, kv heads per grid step) -------------------
 
-# key: (B, H, KH, Sq, Sk, D, dtype)
-_TRAIN_CACHE: Dict[Tuple[int, int, int, int, int, int, str],
+# key: (B, H, KH, Sq, Sk, D, dtype, Dv)
+_TRAIN_CACHE: Dict[Tuple[int, int, int, int, int, int, str, int],
                    Tuple[int, int, int]] = {}
 
 _TRAIN_CANDIDATES: Tuple[int, ...] = (128, 256, 512)
@@ -131,21 +131,24 @@ def _seq_blocks(S: int, sublanes: int) -> Tuple[int, ...]:
 
 
 def _train_vmem_bytes(bq: int, bk: int, kv: int, G: int, D: int,
-                      itemsize: int) -> int:
+                      itemsize: int, Dv: int | None = None) -> int:
     """Per-step VMEM of the largest of the training kernels: its
     double-buffered tiles (the merged-head lanes rounded up to 128) and
     row statistics, its f32 scratch (the one-block backward's dQ^T and
     k^T included), and the sub-tiles of one head's work (about eight live
-    128 x 128 f32 tiles)."""
+    128 x 128 f32 tiles).  ``Dv``: v's head width where it is not D (o,
+    dO and dV take it)."""
+    Dv = D if Dv is None else Dv
     r = lambda n: -(-n // 128) * 128
     hb = kv * G
     ql, kl, dp = r(hb * D), r(kv * D), r(D)
+    ol, vl = r(hb * Dv), r(kv * Dv)
     rows = 2 * hb * 4 * 8 * bq                     # lse and delta blocks
-    fwd = (2 * itemsize * (2 * bq * ql + 2 * bk * kl) + rows
-           + 4 * hb * (2 * 8 * bq + D * bq) + 4 * kv * D * bk)
-    bwd = (2 * (itemsize * (3 * bq * ql + 4 * bk * kl) + rows)
-           + 4 * (2 * kv * bk * dp + hb * D * bq + D * bk))
-    dq = (2 * (itemsize * (3 * bq * ql + 2 * bk * kl) + rows)
+    fwd = (2 * itemsize * (bq * (ql + ol) + bk * (kl + vl)) + rows
+           + 4 * hb * (2 * 8 * bq + Dv * bq) + 4 * kv * Dv * bk)
+    bwd = (2 * (itemsize * (bq * (2 * ql + ol) + 2 * bk * (kl + vl)) + rows)
+           + 4 * (kv * bk * (dp + r(Dv)) + hb * D * bq + D * bk))
+    dq = (2 * (itemsize * (bq * (2 * ql + ol) + bk * (kl + vl)) + rows)
           + 4 * hb * bq * dp)
     return max(fwd, bwd, dq) + 8 * 4 * 128 * 128
 
@@ -157,7 +160,8 @@ def _lane_ok(heads: int, total: int, D: int) -> bool:
 
 
 def best_train_blocks(B: int, H: int, KH: int, Sq: int, Sk: int, D: int,
-                      dtype=jnp.float32) -> Tuple[int, int, int]:
+                      dtype=jnp.float32,
+                      Dv: int | None = None) -> Tuple[int, int, int]:
     """Memoized (bq, bk, kv_heads) for the training attention kernels.
 
     The decode rule's shape: drop what is past the VMEM budget, then the
@@ -167,9 +171,10 @@ def best_train_blocks(B: int, H: int, KH: int, Sq: int, Sk: int, D: int,
     a third of a microsecond), then the fewest grid steps.  Self-attention
     (Sq == Sk) takes square blocks, which the kernels' causal fast path
     needs; a head group must be a legal lane block of the merged-head
-    layout."""
+    layout, for q and k's width D and v's width ``Dv`` (default D)."""
+    Dv = D if Dv is None else int(Dv)
     key = (int(B), int(H), int(KH), int(Sq), int(Sk), int(D),
-           jnp.dtype(dtype).name)
+           jnp.dtype(dtype).name, Dv)
     hit = _TRAIN_CACHE.get(key)
     if hit is not None:
         return hit
@@ -178,12 +183,14 @@ def best_train_blocks(B: int, H: int, KH: int, Sq: int, Sk: int, D: int,
     G = H // KH
     pad = lambda S, b: -(-S // b) * b
     kvs = [kv for kv in range(1, KH + 1) if KH % kv == 0
-           and _lane_ok(kv, KH, D) and _lane_ok(kv * G, H, D)]
+           and all(_lane_ok(kv, KH, w) and _lane_ok(kv * G, H, w)
+                   for w in (D, Dv))]
     cands = [(bq, bk, kv)
              for bq in _seq_blocks(Sq, sub) for bk in _seq_blocks(Sk, sub)
              if Sq != Sk or bq == bk
              for kv in kvs
-             if _train_vmem_bytes(bq, bk, kv, G, D, itemsize) <= _VMEM_BUDGET]
+             if _train_vmem_bytes(bq, bk, kv, G, D, itemsize,
+                                  Dv) <= _VMEM_BUDGET]
     if not cands:
         cands = [(_seq_blocks(Sq, sub)[0], _seq_blocks(Sk, sub)[0], kvs[0])]
     best = min(cands, key=lambda c: (pad(Sq, c[0]) + pad(Sk, c[1]), c[2],

@@ -554,6 +554,9 @@ class TrainHistory:
     # robust-aggregation defense (JSON-able: they ride episode checkpoints)
     anomaly_scores: List[Dict[str, List[float]]] = field(default_factory=list)
     quarantined: List[List[int]] = field(default_factory=list)
+    # per round, (local step, dropless MoE layer, held expert): the
+    # (token, choice) rows routed to each held expert (MoE configs)
+    expert_rows: List[List[List[List[int]]]] = field(default_factory=list)
 
 
 class Trainer:
@@ -640,9 +643,13 @@ class Trainer:
                       if isinstance(metrics, dict) else None)
                 # the host's waits on the round just dispatched: its losses
                 # and its rollback flag
+                rows = (metrics.get("expert_rows")
+                        if isinstance(metrics, dict) else None)
                 with jax.profiler.TraceAnnotation("train.pull"):
-                    loss = jax.device_get(metrics["loss"])
+                    loss, rows = jax.device_get((metrics["loss"], rows))
                     rolled_back = rb is not None and bool(jax.device_get(rb))
+                if rows is not None:
+                    history.expert_rows.append(np.asarray(rows).tolist())
                 losses = np.asarray(loss, np.float64).reshape(-1)
                 history.losses.extend(float(x) for x in losses)
                 history.round_losses.append(float(losses.mean()))

@@ -16,7 +16,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from .layers import apply_rope, dense, init_dense
+from .layers import apply_rope, dense, init_dense, rmsnorm
 
 NEG_INF = -1e30
 
@@ -85,7 +85,7 @@ def naive_attention(q, k, v, q_pos, k_pos, window: int = 0) -> jax.Array:
     p = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("bhgqk,bkhd->bqhgd", p.astype(v.dtype), v,
                    preferred_element_type=jnp.float32)
-    return o.reshape(B, Sq, H, D).astype(q.dtype)
+    return o.reshape(B, Sq, H, v.shape[-1]).astype(q.dtype)
 
 
 def _chunk_kv(k, v, k_pos, kv_chunk):
@@ -97,7 +97,7 @@ def _chunk_kv(k, v, k_pos, kv_chunk):
         k_pos = jnp.pad(k_pos, (0, pad), constant_values=-1)
     n = (Sk + pad) // kv_chunk
     kc = k.reshape(B, n, kv_chunk, KH, D).transpose(1, 0, 2, 3, 4)
-    vc = v.reshape(B, n, kv_chunk, KH, D).transpose(1, 0, 2, 3, 4)
+    vc = v.reshape(B, n, kv_chunk, KH, -1).transpose(1, 0, 2, 3, 4)
     pc = k_pos.reshape(n, kv_chunk)
     return kc, vc, pc, pad
 
@@ -122,7 +122,7 @@ def _flash_fwd_scan(q, k, v, q_pos, k_pos, window, kv_chunk,
 
     m0 = jnp.full((B, KH, G, Sq), NEG_INF, jnp.float32)
     l0 = jnp.zeros((B, KH, G, Sq), jnp.float32)
-    a0 = jnp.zeros((B, Sq, KH, G, D), jnp.float32)
+    a0 = jnp.zeros((B, Sq, KH, G, v.shape[-1]), jnp.float32)
 
     def body(carry, xs):
         m, l, acc = carry
@@ -156,17 +156,17 @@ def _flash_attention(q, k, v, q_pos, k_pos, window: int, kv_chunk: int,
                      s_low_precision: bool = False):
     out, _ = _flash_fwd_scan(q, k, v, q_pos, k_pos, window, kv_chunk,
                              s_low_precision)
-    B, Sq, H, D = q.shape
-    return out.reshape(B, Sq, H, D).astype(q.dtype)
+    B, Sq, H, _ = q.shape
+    return out.reshape(B, Sq, H, v.shape[-1]).astype(q.dtype)
 
 
 def _flash_fwd(q, k, v, q_pos, k_pos, window, kv_chunk,
                s_low_precision=False):
     out, lse = _flash_fwd_scan(q, k, v, q_pos, k_pos, window, kv_chunk,
                                s_low_precision)
-    B, Sq, H, D = q.shape
+    B, Sq, H, _ = q.shape
     res = (q, k, v, q_pos, k_pos, out, lse)
-    return out.reshape(B, Sq, H, D).astype(q.dtype), res
+    return out.reshape(B, Sq, H, v.shape[-1]).astype(q.dtype), res
 
 
 def _flash_bwd(window, kv_chunk, s_low_precision, res, dout):
@@ -180,7 +180,7 @@ def _flash_bwd(window, kv_chunk, s_low_precision, res, dout):
     kc, vc, pc, pad = _chunk_kv(k, v, k_pos, kv_chunk)
 
     qf = q.astype(jnp.float32).reshape(B, Sq, KH, G, D)
-    do = dout.astype(jnp.float32).reshape(B, Sq, KH, G, D)
+    do = dout.astype(jnp.float32).reshape(B, Sq, KH, G, v.shape[-1])
     delta = jnp.sum(do * out, axis=-1).transpose(0, 2, 3, 1)   # (B,KH,G,Sq)
 
     dq0 = jnp.zeros((B, Sq, KH, G, D), jnp.float32)
@@ -208,7 +208,8 @@ def _flash_bwd(window, kv_chunk, s_low_precision, res, dout):
     dq, (dk_c, dv_c) = jax.lax.scan(body, dq0, (kc, vc, pc))
     n = dk_c.shape[0]
     dk = dk_c.transpose(1, 0, 2, 3, 4).reshape(B, n * kv_chunk, KH, D)[:, :Sk]
-    dv = dv_c.transpose(1, 0, 2, 3, 4).reshape(B, n * kv_chunk, KH, D)[:, :Sk]
+    dv = dv_c.transpose(1, 0, 2, 3, 4).reshape(B, n * kv_chunk, KH,
+                                               v.shape[-1])[:, :Sk]
     import numpy as np
     zero_i = lambda x: np.zeros(x.shape, jax.dtypes.float0)
     return (dq.reshape(B, Sq, H, D).astype(q.dtype), dk.astype(k.dtype),
@@ -256,7 +257,7 @@ def online_attention(q, k, v, q_pos, k_pos, *, window: int = 0,
                                     min(kv_chunk, Sk), s_low_precision)
 
         out = jax.lax.map(_one, (qb.transpose(1, 0, 2, 3, 4), pb))
-        return out.transpose(1, 0, 2, 3, 4).reshape(B, Sq, H, D)
+        return out.transpose(1, 0, 2, 3, 4).reshape(B, Sq, H, v.shape[-1])
 
     return _flash_attention(q, k, v, q_pos, k_pos, window,
                             min(kv_chunk, Sk), s_low_precision)
@@ -266,14 +267,16 @@ def _kernel_covers(q, k, v, causal_prefix: bool, s_low_precision: bool) -> bool:
     """Whether ``kernels.flash_attention.flash_attention_train`` computes
     this call: causal self-attention over positions 0..S-1 (what
     ``causal_prefix`` asserts), any window, f32 or bf16 operands of one
-    dtype, and a head size the MXU tiles take.  ``s_low_precision`` asks
-    for bf16-accumulated scores, which the kernel does not do."""
+    dtype, and q/k and v head sizes the MXU tiles take.
+    ``s_low_precision`` asks for bf16-accumulated scores, which the
+    kernel does not do."""
     dtypes = {q.dtype, k.dtype, v.dtype}
     return (causal_prefix and q.shape[1] == k.shape[1]
             and not s_low_precision
             and len(dtypes) == 1 and dtypes <= {jnp.dtype(jnp.float32),
                                                 jnp.dtype(jnp.bfloat16)}
-            and q.shape[-1] % 8 == 0 and q.shape[-1] <= 256)
+            and all(x.shape[-1] % 8 == 0 and x.shape[-1] <= 256
+                    for x in (q, v)))
 
 
 def run_attention(q, k, v, q_pos, k_pos, *, impl: str = "chunked",
@@ -309,6 +312,66 @@ def run_attention(q, k, v, q_pos, k_pos, *, impl: str = "chunked",
 # ---------------------------------------------------------------------------
 # block-level entry points
 # ---------------------------------------------------------------------------
+
+# RMSNorm of the kv latent: DeepSeek's kv_a_layernorm keeps its module
+# default eps, not the config's rms_norm_eps
+MLA_LATENT_EPS = 1e-6
+
+
+def init_mla(cfg, key, dtype) -> dict:
+    """Multi-head latent attention without a query low-rank (DeepSeek-V3 at
+    ``q_lora_rank`` null): q_proj, kv_a_proj (latent + the shared rotary
+    key), the latent's RMSNorm, kv_b_proj (per-head k and v from the
+    latent), o_proj; no biases."""
+    d, h, r = cfg.d_model, cfg.num_heads, cfg.kv_lora_rank
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    ks = jax.random.split(key, 4)
+    return {
+        "q_proj": init_dense(ks[0], d, h * (dn + dr), dtype),
+        "kv_a_proj": init_dense(ks[1], d, r + dr, dtype),
+        "kv_norm": {"scale": jnp.ones((r,), dtype)},
+        "kv_b_proj": init_dense(ks[2], r, h * (dn + dv), dtype),
+        "o_proj": init_dense(ks[3], h * dv, d, dtype),
+    }
+
+
+def _rope_pairs(x, positions, theta):
+    """DeepSeek's rotary layout: the rotated dims come as adjacent pairs,
+    de-interleaved into halves and then rotated as ``apply_rope`` does."""
+    x = jnp.concatenate([x[..., 0::2], x[..., 1::2]], axis=-1)
+    return apply_rope(x, positions, theta)
+
+
+def mla_attention(cfg, p, x, positions, *, lora=None, lora_scale=1.0,
+                  impl="chunked", kv_chunk=512, q_chunk=0,
+                  dense_impl: str = "einsum"):
+    """Causal MLA over a full sequence (training).  LoRA adapts
+    ``q_proj`` and ``kv_b_proj``.  q and k heads are qk_nope + qk_rope
+    wide (softmax scale their width^-1/2), v heads v_head_dim wide: the
+    attention kernels take the two widths apart."""
+    B, S, _ = x.shape
+    h, r = cfg.num_heads, cfg.kv_lora_rank
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+
+    def _l(name):
+        return None if lora is None or name not in lora else lora[name]
+
+    q = dense(x, p["q_proj"]["w"], None, _l("q_proj"), lora_scale,
+              impl=dense_impl).reshape(B, S, h, dn + dr)
+    ckv = dense(x, p["kv_a_proj"]["w"], impl=dense_impl)
+    c = rmsnorm(ckv[..., :r], p["kv_norm"]["scale"], MLA_LATENT_EPS)
+    kv = dense(c, p["kv_b_proj"]["w"], None, _l("kv_b_proj"), lora_scale,
+               impl=dense_impl).reshape(B, S, h, dn + dv)
+    pos = jnp.broadcast_to(positions, (B, S))
+    q_pe = _rope_pairs(q[..., dn:], pos, cfg.rope_theta)
+    k_pe = _rope_pairs(ckv[:, :, None, r:], pos, cfg.rope_theta)
+    q = jnp.concatenate([q[..., :dn], q_pe], axis=-1)
+    k = jnp.concatenate([kv[..., :dn],
+                         jnp.broadcast_to(k_pe, (B, S, h, dr))], axis=-1)
+    o = run_attention(q, k, kv[..., dn:], positions, positions, impl=impl,
+                      kv_chunk=kv_chunk, q_chunk=q_chunk, causal_prefix=True)
+    return dense(o.reshape(B, S, h * dv), p["o_proj"]["w"], impl=dense_impl)
+
 
 def self_attention(cfg, p, x, positions, *, lora=None, lora_scale=1.0,
                    impl="chunked", kv_chunk=512, q_chunk=0,

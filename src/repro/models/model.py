@@ -24,6 +24,7 @@ IGNORE_ID = -1
 _ATTN_TARGETS = {"q": ("wq",), "k": ("wk",), "v": ("wv",), "o": ("wo",)}
 _MLP_TARGETS = {"gate": "w_gate", "up": "w_up", "down": "w_down"}
 _SSM_TARGETS = {"ssm_in": "in_proj", "ssm_out": "out_proj"}
+_MLA_TARGETS = ("q_proj", "kv_b_proj")
 
 
 # ---------------------------------------------------------------------------
@@ -31,12 +32,17 @@ _SSM_TARGETS = {"ssm_in": "in_proj", "ssm_out": "out_proj"}
 # ---------------------------------------------------------------------------
 
 def init_params(cfg: ArchConfig, key, dtype=jnp.float32) -> dict:
-    k1, k2 = jax.random.split(key)
-    return {
+    """A config with leading layers (``cfg.prefix``) adds "prefix": their
+    blocks, as ``stack.init_stack(pattern=cfg.prefix)`` lays them out."""
+    k1, k2, k3 = jax.random.split(key, 3)
+    p = {
         "embed": init_embeddings(cfg, k1, dtype),
         "layers": stack_mod.init_stack(cfg, k2, dtype),
         "final_norm": init_norm(cfg, cfg.d_model, dtype),
     }
+    if cfg.prefix:
+        p["prefix"] = stack_mod.init_stack(cfg, k3, dtype, pattern=cfg.prefix)
+    return p
 
 
 def abstract_params(cfg: ArchConfig, dtype=jnp.bfloat16):
@@ -47,6 +53,11 @@ def abstract_params(cfg: ArchConfig, dtype=jnp.bfloat16):
 def _lora_dims(cfg: ArchConfig, pat, target: str) -> Optional[Tuple[str, int, int]]:
     """-> (block_key, d_in, d_out) for a target name, or None if absent."""
     h, kh, hd, d = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim, cfg.d_model
+    if target in _MLA_TARGETS and pat.mixer == "mla":
+        if target == "q_proj":
+            return ("mixer", d, h * cfg.qk_head_dim)
+        return ("mixer", cfg.kv_lora_rank,
+                h * (cfg.qk_nope_head_dim + cfg.v_head_dim))
     if target in _ATTN_TARGETS and pat.mixer == "attention":
         if target == "q":
             return ("mixer", d, h * hd)
@@ -71,12 +82,24 @@ def _lora_dims(cfg: ArchConfig, pat, target: str) -> Optional[Tuple[str, int, in
 
 def init_lora_stack(cfg: ArchConfig, key, rank: Optional[int] = None,
                     dtype=jnp.float32) -> Tuple[Any, ...]:
-    """LoRA adapters, stacked over repeats, tuple over pattern positions."""
+    """LoRA adapters, stacked over repeats, tuple over pattern positions.
+    With leading layers (``cfg.prefix``): {"prefix": theirs, laid out the
+    same way, "layers": the repeating ones'}."""
+    if cfg.prefix:
+        kp, kl = jax.random.split(key)
+        return {"prefix": _lora_stack(cfg, cfg.prefix, 1, kp, rank, dtype),
+                "layers": _lora_stack(cfg, cfg.pattern, cfg.pattern_repeats,
+                                      kl, rank, dtype)}
+    return _lora_stack(cfg, cfg.pattern, cfg.pattern_repeats, key, rank,
+                       dtype)
+
+
+def _lora_stack(cfg, pattern, R, key, rank, dtype):
     rank = rank or cfg.lora_rank
-    P, R = len(cfg.pattern), cfg.pattern_repeats
+    P = len(pattern)
     keys = jax.random.split(key, P * R).reshape(P, R)
     out = []
-    for pi, pat in enumerate(cfg.pattern):
+    for pi, pat in enumerate(pattern):
         per_rep = []
         for ri in range(R):
             kk = jax.random.split(keys[pi, ri], max(len(cfg.lora_targets), 1))
@@ -131,6 +154,12 @@ def forward(cfg: ArchConfig, params: dict, tokens: jax.Array, *,
     S = tokens.shape[1] + (frontend_emb.shape[1] if frontend_emb is not None else 0)
     positions = jnp.arange(S, dtype=jnp.int32)
     x = _embed_inputs(cfg, params, tokens, frontend_emb, positions)
+    if cfg.prefix:
+        x, _, _ = stack_mod.apply_stack(
+            cfg, params["prefix"], x, positions=positions,
+            lora=None if lora is None else lora["prefix"], rt=rt,
+            mode=mode, pattern=cfg.prefix)
+        lora = None if lora is None else lora["layers"]
     x, _, aux = stack_mod.apply_stack(cfg, params["layers"], x,
                                       positions=positions, lora=lora, rt=rt,
                                       mode="train" if mode == "train" else mode)

@@ -130,3 +130,87 @@ def apply_moe(cfg, p: dict, x: jax.Array, *, group_size: int = 128,
     if cfg.shared_expert:
         out = out + swiglu_mlp(cfg, x, p["shared"])
     return out, aux.astype(jnp.float32)
+
+
+# ---------------------------------------------------------------------------
+# dropless routing over the experts this chip holds
+# ---------------------------------------------------------------------------
+
+def init_moe_dropless(cfg, key, dtype) -> dict:
+    """Router over all ``num_experts`` and its selection bias, the held
+    experts' SwiGLU weights, and the shared expert."""
+    d, f, e = cfg.d_model, cfg.moe_d_ff or cfg.d_ff, cfg.held_experts
+    ks = jax.random.split(key, 6)
+    nrm = lambda k, shp, fan: (jax.random.normal(k, shp, jnp.float32)
+                               * fan ** -0.5).astype(dtype)
+    p = {"router": init_dense(ks[0], d, cfg.num_experts, dtype, scale=0.02),
+         "w_gate": nrm(ks[1], (e, d, f), d), "w_up": nrm(ks[2], (e, d, f), d),
+         "w_down": nrm(ks[3], (e, f, d), f)}
+    p["router"]["bias"] = jnp.zeros((cfg.num_experts,), dtype)
+    if cfg.shared_expert:
+        p["shared"] = init_mlp(cfg.replace(d_ff=cfg.shared_d_ff or cfg.d_ff),
+                               ks[4], dtype)
+    return p
+
+
+def route(cfg, p: dict, x2: jax.Array):
+    """(ids (T, k) of the chosen experts over all of them, gate weights
+    (T, k) f32), as DeepSeek-V3's gate (``noaux_tc`` with one group)
+    computes them: scores are the sigmoid of the router logits, in float32
+    at full precision; the correction bias moves the choice only; the
+    chosen scores, normalised to sum 1, times routed_scale, are the
+    weights."""
+    logits = jnp.dot(x2.astype(jnp.float32),
+                     p["router"]["w"].astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    scores = jax.nn.sigmoid(logits)
+    choose = scores + p["router"]["bias"].astype(jnp.float32)
+    _, ids = jax.lax.top_k(choose, cfg.experts_per_token)
+    w = jnp.take_along_axis(scores, ids, axis=-1)
+    w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+    return ids, w * cfg.routed_scale
+
+
+def apply_moe_dropless(cfg, p: dict, x: jax.Array):
+    """Returns (output (B, S, d), rows (held,) int32: the (token, choice)
+    rows routed to each held expert).
+
+    Routes every token over all ``num_experts``; each (token, choice) row
+    that picks a held expert reaches it (no capacity, nothing dropped),
+    rows sorted by expert through ``kernels.grouped_matmul``; the output
+    is the held experts' part of the mixture plus the shared expert.
+    Phases: ``moe.route`` (gate, top-k, sort and gather), ``moe.experts``
+    (the grouped matmuls, forward and dX), ``moe.combine`` (un-permute and
+    weighted sum), ``moe.shared``."""
+    from ..kernels.grouped_matmul import expert_swiglu
+
+    B, S, d = x.shape
+    E, k = cfg.held_experts, cfg.experts_per_token
+    x2 = x.reshape(B * S, d)
+    with jax.named_scope("moe.route"):
+        ids, w = route(cfg, p, x2)
+        local = (ids - cfg.first_expert).reshape(-1)
+        held = (local >= 0) & (local < E)
+        expert = jnp.where(held, local, E)                # E: not held here
+        order = jnp.argsort(expert, stable=True)
+        group_sizes = jnp.bincount(expert, length=E + 1).astype(jnp.int32)
+        xs = jnp.take(x2, order // k, axis=0)
+        gate = jnp.take(jnp.where(held, w.reshape(-1), 0.0), order)
+    with jax.named_scope("moe.experts"):
+        ys = expert_swiglu(xs, gate, group_sizes, p["w_gate"], p["w_up"],
+                           p["w_down"])
+    with jax.named_scope("moe.combine"):
+        out = jnp.sum(jnp.take(ys, jnp.argsort(order), axis=0)
+                      .reshape(B * S, k, d), axis=1)
+    out = out.astype(x.dtype).reshape(B, S, d)
+    if cfg.shared_expert:
+        # the backward keeps the gate and up projections (the matmul
+        # outputs) alone and makes the activation again from them: at the
+        # Moonlight cut's SFL round (K=5 x S=2048 on the server) autodiff's
+        # own residuals take 1.73 GB more and no longer fit a v5e
+        shared = jax.checkpoint(
+            lambda h: swiglu_mlp(cfg, h, p["shared"]),
+            policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
+        with jax.named_scope("moe.shared"):
+            out = out + shared(x)
+    return out, group_sizes[:E]

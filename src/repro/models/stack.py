@@ -84,12 +84,18 @@ def init_block(cfg, pat, key, dtype) -> dict:
     p: dict = {"norm1": init_norm(cfg, cfg.d_model, dtype)}
     if pat.mixer == "attention":
         p["mixer"] = attn_mod.init_attention(cfg, ks[0], dtype)
+    elif pat.mixer == "mla":
+        p["mixer"] = attn_mod.init_mla(cfg, ks[0], dtype)
     else:
         p["mixer"] = ssm_mod.init_mamba(cfg, ks[0], dtype)
     if pat.mlp != "none":
         p["norm2"] = init_norm(cfg, cfg.d_model, dtype)
-        p["mlp"] = (moe_mod.init_moe(cfg, ks[1], dtype) if pat.mlp == "moe"
-                    else init_mlp(cfg, ks[1], dtype))
+        if pat.mlp == "dense":
+            p["mlp"] = init_mlp(cfg, ks[1], dtype)
+        elif cfg.moe_dispatch == "dropless":
+            p["mlp"] = moe_mod.init_moe_dropless(cfg, ks[1], dtype)
+        else:
+            p["mlp"] = moe_mod.init_moe(cfg, ks[1], dtype)
     return p
 
 
@@ -103,8 +109,10 @@ def apply_block(cfg, pat, p: dict, x, *, positions, lora, lora_scale, rt: Runtim
                 mode: str, cache=None, cur_index=None, cache_len: int = 0,
                 block_tables=None, adapter_idx=None):
     """mode: "train" | "prefill" | "decode" | "chunk".  Returns
-    (x, cache_out, aux).  ``block_tables`` switches decode onto the paged
-    KV pool ((B, MP) page ids; cache is then the (KH, NP, PS, D) pool);
+    (x, cache_out, aux); in mode "train", which keeps no cache, the second
+    value is a dropless MoE layer's count of the rows routed to each
+    expert it holds, else None.  ``block_tables`` switches decode onto the
+    paged KV pool ((B, MP) page ids; cache is then the (KH, NP, PS, D) pool);
     mode "chunk" is one paged-prefill chunk (block_tables (MP,), cur_index
     the chunk's absolute start).  ``adapter_idx`` (decode only) makes the
     LoRA leaves (A, ...) pools with per-slot adapter selection
@@ -112,7 +120,15 @@ def apply_block(cfg, pat, p: dict, x, *, positions, lora, lora_scale, rt: Runtim
     aux = jnp.zeros((), jnp.float32)
     h = apply_norm(cfg, x, p["norm1"])
     cache_out = cache
-    if pat.mixer == "attention":
+    if pat.mixer == "mla":
+        if mode != "train":
+            raise NotImplementedError("latent attention trains only: it "
+                                      "has no decode cache yet")
+        m = attn_mod.mla_attention(
+            cfg, p["mixer"], h, positions, lora=_mixer_lora(lora),
+            lora_scale=lora_scale, impl=rt.attn_impl, kv_chunk=rt.kv_chunk,
+            q_chunk=rt.q_chunk, dense_impl=rt.dense_impl)
+    elif pat.mixer == "attention":
         if mode == "decode" and block_tables is not None:
             m, cache_out = attn_mod.paged_decode_attention(
                 cfg, p["mixer"], h, cache, block_tables, cur_index,
@@ -165,7 +181,9 @@ def apply_block(cfg, pat, p: dict, x, *, positions, lora, lora_scale, rt: Runtim
     x = x + m
     if pat.mlp != "none":
         h = apply_norm(cfg, x, p["norm2"])
-        if pat.mlp == "moe":
+        if pat.mlp == "moe" and cfg.moe_dispatch == "dropless":
+            mo, cache_out = moe_mod.apply_moe_dropless(cfg, p["mlp"], h)
+        elif pat.mlp == "moe":
             specs = ((rt.dp_axes, rt.tp_axis)
                      if rt.moe_constraints and rt.dp_axes else None)
             mo, aux = moe_mod.apply_moe(cfg, p["mlp"], h,
@@ -185,13 +203,15 @@ def apply_block(cfg, pat, p: dict, x, *, positions, lora, lora_scale, rt: Runtim
 # stack init
 # ---------------------------------------------------------------------------
 
-def init_stack(cfg, key, dtype) -> Tuple[dict, ...]:
-    """tuple over pattern positions; leaves stacked over repeats."""
-    P = len(cfg.pattern)
-    R = cfg.pattern_repeats
+def init_stack(cfg, key, dtype, pattern=None) -> Tuple[dict, ...]:
+    """tuple over pattern positions; leaves stacked over repeats.
+    ``pattern=cfg.prefix`` makes the leading layers: one repeat of them."""
+    pattern = cfg.pattern if pattern is None else pattern
+    P = len(pattern)
+    R = cfg.pattern_repeats if pattern is cfg.pattern else 1
     keys = jax.random.split(key, P * R).reshape(P, R)
     out = []
-    for pi, pat in enumerate(cfg.pattern):
+    for pi, pat in enumerate(pattern):
         per_rep = [init_block(cfg, pat, keys[pi, ri], dtype) for ri in range(R)]
         out.append(jax.tree.map(lambda *xs: jnp.stack(xs), *per_rep))
     return tuple(out)
@@ -234,12 +254,19 @@ def apply_stack(cfg, stack_params, x, *, positions, lora=None, rt: Runtime,
                 cache_len: int = 0,
                 rep_slice: Optional[Tuple[int, int]] = None,
                 rep_gate: Optional[Tuple[Any, Any]] = None,
-                lora_scale=None, block_tables=None, adapter_idx=None):
-    """Run (a slice of) the layer stack.
+                lora_scale=None, block_tables=None, adapter_idx=None,
+                pattern=None):
+    """Run (a slice of) the layer stack.  Returns (x, new_caches,
+    aux_loss_sum); in mode "train", which keeps no cache, the second value
+    is (layers, held experts) int32: the rows each dropless MoE layer of
+    the slice routed to each expert it holds, in layer order, or None
+    where the slice has no such layer.
+
+    ``pattern=cfg.prefix`` runs the leading layers (``init_stack`` made
+    with the same pattern) in place of the repeating ones.
 
     ``rep_slice=(a, b)`` runs pattern repeats [a, b) — the SFL split point
     in repeat units.  ``caches``/returned caches follow the same slice.
-    Returns (x, new_caches, aux_loss_sum).
 
     ``rep_gate=(lo, hi)`` — per-call boundary mask for heterogeneous split
     points (train mode only): repeat i of the scanned slice is applied iff
@@ -261,7 +288,14 @@ def apply_stack(cfg, stack_params, x, *, positions, lora=None, rt: Runtime,
     position 1 so the depth scan still slices the leading repeat axis and
     each scanned block sees an ``(A, ...)`` pool (multi-tenant serving).
     """
-    P = len(cfg.pattern)
+    pattern = cfg.pattern if pattern is None else pattern
+    P = len(pattern)
+    leaves = jax.tree.leaves(stack_params)
+    if mode == "train" and rep_slice is None and leaves \
+            and leaves[0].shape[0] == 0:
+        # no repeats here (the client of a split at the leading layers'
+        # end): nothing to run
+        return x, None, jnp.zeros((), jnp.float32)
     lora_stack = lora if lora is not None else tuple([None] * P)
     scale = (cfg.lora_alpha / cfg.lora_rank) if lora_scale is None else lora_scale
     gate_lo, gate_hi = rep_gate if rep_gate is not None else (None, None)
@@ -290,7 +324,7 @@ def apply_stack(cfg, stack_params, x, *, positions, lora=None, rt: Runtime,
         x, aux = carry
         p_slices, l_slices, c_slices = xs
         c_outs = []
-        for pi, pat in enumerate(cfg.pattern):
+        for pi, pat in enumerate(pattern):
             x, c_out, a = apply_block(
                 cfg, pat, p_slices[pi], x, positions=positions,
                 lora=None if l_slices is None else l_slices[pi],
@@ -365,5 +399,8 @@ def apply_stack(cfg, stack_params, x, *, positions, lora=None, rt: Runtime,
     (x, aux), cache_out = jax.lax.scan(
         run, (x, jnp.zeros((), jnp.float32)), xs)
     if mode == "train":
-        cache_out = None
+        # (repeats, E) per position with a dropless MoE layer -> layers
+        rows = [r for r in cache_out if r is not None]
+        cache_out = (jnp.stack(rows, 1).reshape(-1, rows[0].shape[-1])
+                     if rows else None)
     return x, cache_out, aux

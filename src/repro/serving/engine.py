@@ -770,6 +770,9 @@ class ServingEngine:
         if self.paged:
             evict_np = self._evict_req.copy()
             behind_np = self._evict_behind.copy()
+            # a copy: on the CPU a host array can be handed to the step
+            # without one, and the reset below could land before it runs
+            poke_np = self._nan_poke.copy()
             mt = ((self._aslot, self._tenant)
                   if self.adapters is not None else ())
             (nxt, done, victim, bad, self.caches, self._pager, self._bt,
@@ -779,7 +782,7 @@ class ServingEngine:
                 self._bt, self._last, self._positions, self._live,
                 self._uids, self._ngen, self._maxnew, self._eos, self._age,
                 self._deadline, jnp.asarray(evict_np),
-                jnp.asarray(self._nan_poke), *mt)
+                jnp.asarray(poke_np), *mt)
             self._evict_req[:] = False
             self._evict_behind[:] = False
             self._nan_poke[:] = False

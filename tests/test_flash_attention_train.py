@@ -131,6 +131,47 @@ def test_f32_operands_match_autodiff_of_naive_attention(case):
                                    err_msg=f"d{name}")
 
 
+# latent attention (models/attention.py::mla_attention): q and k heads
+# wider than v heads, 192 and 128 in Moonlight; (B, S, H, D, Dv, bq) with
+# one block each way (the single backward kernel) and several (dK/dV, dQ)
+QK_V_CASES = {"one_block": (2, 64, 2, 48, 32, 64),
+              "blocks": (1, 64, 2, 48, 32, 32)}
+
+
+@pytest.mark.parametrize("case", sorted(QK_V_CASES))
+def test_wider_qk_than_v_heads_match_references(case):
+    """Forward, log-sum-exp and gradients at qk width != v width, against
+    ref.py's contract reference (bf16 operands) and, with f32 operands,
+    autodiff of the model's naive attention."""
+    B, S, H, D, Dv, blk = QK_V_CASES[case]
+    ks = jax.random.split(jax.random.key(3), 4)
+    q, k = (jax.random.normal(kk, (B, S, H, D), jnp.float32) for kk in ks[:2])
+    v, do = (jax.random.normal(kk, (B, S, H, Dv), jnp.float32)
+             for kk in ks[2:])
+    kern = lambda q, k, v: flash_attention_train(
+        q, k, v, bq=blk, bk=blk, kv_heads=2, interpret=True)
+    o, vjp = jax.vjp(kern, q, k, v)
+    assert o.shape == (B, S, H, Dv)
+    o_ref, lse = flash_attention_train_ref(_t(q), _t(k), _t(v),
+                                           mxu_dtype=jnp.bfloat16)
+    np.testing.assert_allclose(_f32(o), _f32(_t(o_ref)), **TOL[jnp.float32])
+    refs = flash_attention_train_bwd_ref(_t(q), _t(k), _t(v), o_ref, lse,
+                                         _t(do), mxu_dtype=jnp.bfloat16)
+    for name, g, r in zip("qkv", vjp(do), refs):
+        np.testing.assert_allclose(_f32(g), _f32(_t(r)), **TOL[jnp.float32],
+                                   err_msg=f"d{name}")
+    pos = jnp.arange(S)
+    with jax.default_matmul_precision("highest"):
+        o, vjp = jax.vjp(kern, q, k, v)
+        o_n, vjp_n = jax.vjp(lambda q, k, v: naive_attention(q, k, v, pos,
+                                                             pos), q, k, v)
+        grads, refs = vjp(do), vjp_n(do)
+    np.testing.assert_allclose(_f32(o), _f32(o_n), atol=2e-5, rtol=2e-5)
+    for name, g, r in zip("qkv", grads, refs):
+        np.testing.assert_allclose(_f32(g), _f32(r), atol=5e-5, rtol=5e-5,
+                                   err_msg=f"d{name}")
+
+
 def test_mxu_dtype_follows_input_dtype_and_precision():
     assert mxu_dtype_for(jnp.float32) == jnp.bfloat16
     assert mxu_dtype_for(jnp.bfloat16) == jnp.bfloat16

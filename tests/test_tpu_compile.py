@@ -245,3 +245,81 @@ def test_sfl_round_keeps_kernel_names_under_phase_scopes(one_chip, no_cache,
                                        r".*op_name=\"([^\"]+)\"", text, re.M))
     for phase in ("sfl.server_stack", "sfl.client"):
         assert phase in fusion_meta
+
+
+# latent attention at a Moonlight SFL round's pooled server batch (K=5
+# clients x b=1 x S=2048): 16 heads, q and k 192 wide, v 128 (several
+# blocks: dK/dV and dQ)
+MLA_CELL = (5, 2048, 16, 192, 128)
+
+
+def test_mla_attention_kernels_compile(one_chip, no_cache):
+    B, S, H, D, Dv = MLA_CELL
+
+    def loss(q, k, v, g):
+        return jnp.sum(flash_attention_train(q, k, v, interpret=False) * g)
+
+    qk, vs = ((B, S, H, D), jnp.float32), ((B, S, H, Dv), jnp.float32)
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2)), [qk, qk, vs, vs],
+                    one_chip)
+    for scope in ("attn_fwd", "attn_dkv", "attn_dq"):
+        assert len(_named(text, scope)) == 1, scope
+
+
+def test_grouped_matmul_kernels_compile(one_chip, no_cache):
+    """The held experts' SwiGLU at a Moonlight round's shapes (10,240
+    tokens x top-6 rows, 8 experts of 2048 -> 1408), forward and dX: each
+    kernel is an instruction named after its scope, none ``closed_call``
+    (which the LoRA kernels' reader counts)."""
+    from repro.kernels.grouped_matmul import expert_swiglu
+
+    M, d, f, E = 10240 * 6, 2048, 1408, 8
+
+    def loss(xs, gate, gs, wg, wu, wd):
+        y = expert_swiglu(xs, gate, gs, wg, wu, wd, interpret=False)
+        return jnp.sum(y * y)
+
+    text = _compile(jax.grad(loss, argnums=(0, 1)),
+                    [((M, d), jnp.float32), ((M,), jnp.float32),
+                     ((E + 1,), jnp.int32), ((E, d, f), jnp.float32),
+                     ((E, d, f), jnp.float32), ((E, f, d), jnp.float32)],
+                    one_chip)
+    # gate, up and down, and again from the kept rows in the backward
+    assert len(_named(text, "moe_gmm_fwd")) == 6
+    assert len(_named(text, "moe_gmm_dx")) == 3
+    assert not _named(text, "closed_call")
+
+
+def test_gpt2_cells_keep_their_attention_blocks_and_traces(one_chip,
+                                                           no_cache,
+                                                           monkeypatch):
+    """Separate q/k and v widths leave GPT-2's attention as it was: the
+    block rule still picks (512, 512, 2 KV heads) at both cells' pooled
+    shapes, and a round traces each kernel body once per shape: the
+    forward and the one-block backward, for the client's vmapped call and
+    the server's scan (4 traces)."""
+    from repro.configs import TrainConfig, get_arch
+    from repro.core.sfl import SflLLM
+    from repro.kernels import backend
+    from repro.kernels.flash_attention import best_train_blocks, kernel
+    from repro.models.model import init_lora_stack, init_params
+    from repro.optim import adamw
+
+    for B, H in ((15, 12), (5, 16)):
+        assert best_train_blocks(B, H, H, 512, 512, 64) == (512, 512, 2)
+    monkeypatch.setattr(backend, "auto_interpret", lambda: False)
+    monkeypatch.setattr(kernel, "_TRACED", {})
+    cfg = get_arch("gpt2-s").reduced(num_layers=2, d_model=128, vocab=512)
+    sfl = SflLLM(cfg, init_params(cfg, jax.random.key(0)), ell_c=1,
+                 train_cfg=TrainConfig(num_clients=2, batch_size=2,
+                                       local_steps=2),
+                 optimizer=adamw(1e-3))
+    state = sfl.init_state(init_lora_stack(cfg, jax.random.key(1)))
+    shaped = lambda t: jax.tree.map(lambda v: jax.ShapeDtypeStruct(
+        v.shape, v.dtype, sharding=one_chip), t)
+    ids = jax.ShapeDtypeStruct((2, 2, 2, 128), jnp.int32, sharding=one_chip)
+    pc = jax.ShapeDtypeStruct((2,), jnp.float32, sharding=one_chip)
+    sfl._jit_round_part.lower(shaped(sfl.base), shaped(state),
+                              {"tokens": ids, "labels": ids}, pc, pc, None)
+    assert sorted(k[0] for k in kernel._TRACED) == ["attn_bwd", "attn_bwd",
+                                                    "attn_fwd", "attn_fwd"]
