@@ -6,6 +6,7 @@ paper's technique is threaded through every projection this way.
 """
 from __future__ import annotations
 
+import math
 from typing import Optional
 
 import jax
@@ -210,6 +211,32 @@ def swiglu_mlp(cfg, x: jax.Array, p: dict, lora: Optional[dict] = None,
                  w_scale=p["w_down"].get("w_scale"))
 
 
+_GELU_C = math.sqrt(2.0 / math.pi)
+
+
+@jax.custom_vjp
+def gelu_new(h: jax.Array) -> jax.Array:
+    """GPT-2's tanh GELU, computed in f32 and returned in h's dtype.  The
+    backward keeps only ``h`` and recomputes the tanh from it: plain
+    autodiff would save four more f32 arrays of h's shape."""
+    return jax.nn.gelu(h.astype(jnp.float32), approximate=True).astype(h.dtype)
+
+
+def _gelu_new_fwd(h):
+    return gelu_new(h), h
+
+
+def _gelu_new_bwd(h, g):
+    x = h.astype(jnp.float32)
+    t = jnp.tanh(_GELU_C * (x + 0.044715 * x ** 3))
+    dx = 0.5 * (1 + t) + 0.5 * x * (1 - t * t) * _GELU_C * (
+        1 + 3 * 0.044715 * x * x)
+    return ((g.astype(jnp.float32) * dx).astype(h.dtype),)
+
+
+gelu_new.defvjp(_gelu_new_fwd, _gelu_new_bwd)
+
+
 def gelu_mlp(cfg, x: jax.Array, p: dict, lora: Optional[dict] = None,
              lora_scale: float = 1.0, dense_impl: str = "einsum",
              adapter_idx: Optional[jax.Array] = None) -> jax.Array:
@@ -219,7 +246,7 @@ def gelu_mlp(cfg, x: jax.Array, p: dict, lora: Optional[dict] = None,
     h = dense(x, p["w_up"]["w"], p["w_up"].get("b"), lora=_l("up"),
               lora_scale=lora_scale, impl=dense_impl, adapter_idx=adapter_idx,
               w_scale=p["w_up"].get("w_scale"))
-    h = jax.nn.gelu(h.astype(jnp.float32), approximate=True).astype(x.dtype)
+    h = gelu_new(h)
     return dense(h, p["w_down"]["w"], p["w_down"].get("b"), lora=_l("down"),
                  lora_scale=lora_scale, impl=dense_impl,
                  adapter_idx=adapter_idx,

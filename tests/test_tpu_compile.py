@@ -323,3 +323,28 @@ def test_gpt2_cells_keep_their_attention_blocks_and_traces(one_chip,
                               {"tokens": ids, "labels": ids}, pc, pc, None)
     assert sorted(k[0] for k in kernel._TRACED) == ["attn_bwd", "attn_bwd",
                                                     "attn_fwd", "attn_fwd"]
+
+
+def test_gelu_mlp_stack_saves_one_term_a_layer(one_chip, no_cache):
+    """Gradient through a 2-layer depth scan of ``gelu_mlp`` at GPT2-S
+    widths and the S cell's pooled server rows (K 5 x b 3 x S 512), the
+    weights frozen: the scan saves one f32 ``[T, d_ff]`` term a layer (the
+    pre-activation), where autodiff of the tanh GELU saved five."""
+    from repro.configs import get_arch
+    from repro.models import layers
+
+    cfg = get_arch("gpt2-s")
+    L, T = 2, 15 * 512
+
+    def loss(x, p):
+        def body(x, p):
+            return x + layers.gelu_mlp(cfg, x, p), None
+        return jnp.sum(jax.lax.scan(body, x, p)[0] ** 2)
+
+    sd = lambda s: jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
+    p = {"w_up": {"w": sd((L, D_MODEL, D_FF)), "b": sd((L, D_FF))},
+         "w_down": {"w": sd((L, D_FF, D_MODEL)), "b": sd((L, D_MODEL))}}
+    mem = jax.jit(jax.grad(loss)).lower(sd((T, D_MODEL)), p).compile(
+    ).memory_analysis()
+    term = T * D_FF * 4
+    assert mem.temp_size_in_bytes < 2 * L * term
