@@ -4,22 +4,19 @@ reach a target loss, on the synthetic E2E task with a reduced GPT-2.
 Also fits the E(r) convergence model (core.convergence) from the measured
 (rank, steps) pairs — the calibration the paper performs offline for P4.
 
-Training goes through launch.engine (one compiled scan per round); a
-dedicated row compares steps/sec of the seed-style per-step Python loop
-against the compiled round engine on the same workload ("engine/speedup").
+Training goes through launch.engine (one compiled scan per round).
 """
 from __future__ import annotations
 
 import time
 
 import jax
-import numpy as np
 
 from repro.configs import TrainConfig, get_arch
 from repro.core.convergence import fit_convergence_model
-from repro.core.sfl import CentralizedLoRA, SflLLM
-from repro.data import WordTokenizer, batches, e2e_splits, iid_partition, sfl_batches
-from repro.launch.engine import CentralizedRound, SflRound, Trainer
+from repro.core.sfl import CentralizedLoRA
+from repro.data import WordTokenizer, batches, e2e_splits
+from repro.launch.engine import CentralizedRound, Trainer
 from repro import models as M
 from repro.optim import adamw
 
@@ -75,111 +72,7 @@ def steps_to_target(curves, target=None):
     return target, out
 
 
-def engine_speedup(seed: int = 0, steps: int = 48, local_steps: int = 6,
-                   K: int = 3):
-    """steps/sec before (per-step jit dispatch + Python-loop FedAvg) vs
-    after (one jitted scan + in-graph FedAvg per round), same SFL workload."""
-    cfg = get_arch("gpt2-s").reduced(num_layers=4)
-    train, _, _ = e2e_splits(1200, 100, 100, seed=seed)
-    tok = WordTokenizer.from_corpus([e.text for e in train])
-    parts = [np.array(train, dtype=object)[i]
-             for i in iid_partition(len(train), K, seed)]
-    counts = [len(p) for p in parts]
-    key = jax.random.key(seed)
-    params = M.init_params(cfg, key)
-    lora = M.init_lora_stack(cfg, jax.random.key(seed + 1), rank=4)
-    tc = TrainConfig(num_clients=K, batch_size=4, local_steps=local_steps)
-    rounds = steps // local_steps
-
-    def measure(fn):
-        fn()                               # warmup round (compile)
-        t0 = time.time()
-        n = fn()
-        return n / (time.time() - t0)
-
-    # before: the seed execution model — host round-trips K*I times/round
-    sfl = SflLLM(cfg, params, ell_c=2, train_cfg=tc, optimizer=adamw(3e-3),
-                 donate=False)
-    data = sfl_batches(tok, parts, 4, S, rng=seed)
-
-    def per_step_loop():
-        state = sfl.init_state(lora)
-        for _ in range(rounds):
-            for _ in range(local_steps):
-                state, m = sfl.local_step(state, next(data))
-            state = sfl.aggregate(state, counts)
-        jax.block_until_ready(state.lora_client)
-        return rounds * local_steps
-
-    # after: the compiled round engine (scan + in-graph FedAvg, donation on)
-    sfl_after = SflLLM(cfg, params, ell_c=2, train_cfg=tc,
-                       optimizer=adamw(3e-3), donate=True)
-
-    def compiled_rounds():
-        state = sfl_after.init_state(lora)
-        trainer = Trainer(SflRound(sfl_after, counts),
-                          local_steps=local_steps)
-        state, h = trainer.fit(state, data, global_rounds=rounds)
-        jax.block_until_ready(state.lora_client)
-        return len(h.losses)
-
-    before = measure(per_step_loop)
-    after = measure(compiled_rounds)
-    return before, after
-
-
-def runtime_speedup(seed: int = 0, steps: int = 48, local_steps: int = 6,
-                    K: int = 3):
-    """steps/sec with the pre-PR trainer runtime (naive attention, unfused
-    einsum LoRA) vs the new fast defaults (chunked attention + fused LoRA
-    dispatch), both through the compiled round engine."""
-    from repro.models.stack import Runtime, default_train_runtime
-
-    cfg = get_arch("gpt2-s").reduced(num_layers=4)
-    train, _, _ = e2e_splits(1200, 100, 100, seed=seed)
-    tok = WordTokenizer.from_corpus([e.text for e in train])
-    parts = [np.array(train, dtype=object)[i]
-             for i in iid_partition(len(train), K, seed)]
-    counts = [len(p) for p in parts]
-    params = M.init_params(cfg, jax.random.key(seed))
-    lora = M.init_lora_stack(cfg, jax.random.key(seed + 1), rank=4)
-    tc = TrainConfig(num_clients=K, batch_size=4, local_steps=local_steps)
-    rounds = steps // local_steps
-
-    def run_with(rt):
-        sfl = SflLLM(cfg, params, ell_c=2, train_cfg=tc,
-                     optimizer=adamw(3e-3), rt=rt)
-        data = sfl_batches(tok, parts, 4, S, rng=seed)
-
-        def rounds_fn():
-            state = sfl.init_state(lora)
-            trainer = Trainer(SflRound(sfl, counts), local_steps=local_steps)
-            state, h = trainer.fit(state, data, global_rounds=rounds)
-            jax.block_until_ready(state.lora_client)
-            return len(h.losses)
-
-        rounds_fn()                        # warmup round (compile)
-        t0 = time.time()
-        n = rounds_fn()
-        return n / (time.time() - t0)
-
-    before = run_with(Runtime(attn_impl="naive", dense_impl="einsum"))
-    after = run_with(default_train_runtime())
-    return before, after
-
-
 def main(emit):
-    before, after = engine_speedup()
-    emit("engine/speedup", 0.0,
-         f"steps_per_sec_before={before:.2f};steps_per_sec_after={after:.2f};"
-         f"speedup={after / before:.2f}x")
-
-    rt_before, rt_after = runtime_speedup()
-    emit("engine/runtime_defaults", 0.0,
-         f"steps_per_sec_naive_einsum={rt_before:.2f};"
-         f"steps_per_sec_chunked_fused={rt_after:.2f};"
-         f"speedup={rt_after / rt_before:.2f}x")
-
     curves = run()
     target, s2t = steps_to_target(curves)
     for rank, (losses, wall) in curves.items():

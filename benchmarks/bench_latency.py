@@ -69,34 +69,6 @@ SWEEPS = {
 }
 
 
-def _measured_serving(emit):
-    """Ground the analytic model with a real tokens/sec number: the
-    deployment phase the Section V delay model feeds into is the
-    continuous-batching engine serving the fine-tuned adapters."""
-    import jax
-
-    from repro import models as M
-    from repro.models.generate import SampleConfig
-    from repro.serving import Request, ServingEngine
-
-    cfg = get_arch("gpt2-s").reduced(num_layers=2)
-    params = M.init_params(cfg, jax.random.key(0))
-    for row, kw in (("measured/serving_engine", dict(paged=False)),
-                    ("measured/serving_engine_paged", dict(page_size=16))):
-        eng = ServingEngine(cfg, params, max_slots=4, max_len=64,
-                            sc=SampleConfig(greedy=True), **kw)
-        reqs = [Request(uid=i, prompt=list(range(5, 13 + i)),
-                        max_new_tokens=8) for i in range(6)]
-        for r in reqs:
-            eng.submit(r)
-        t0 = time.time()
-        eng.run()
-        wall = time.time() - t0
-        total = sum(len(r.output) for r in reqs)
-        emit(row, wall * 1e6,
-             f"tok_s={total / wall:.1f};requests={len(reqs)}")
-
-
 def main(emit):
     for sweep, points in SWEEPS.items():
         for label, mk in points:
@@ -106,7 +78,6 @@ def main(emit):
             derived = ";".join(f"{k}={v:.1f}" for k, v in row.items())
             red = 100 * (1 - row["proposed"] / row["baseline_a"])
             emit(f"{sweep}/{label}", us, derived + f";reduction_vs_a={red:.1f}%")
-    _measured_serving(emit)
 
 
 if __name__ == "__main__":

@@ -1,81 +1,25 @@
-"""Multi-tenant adapter serving benchmark: batched-gather LoRA dispatch
-overhead + one mixed-tenant engine vs per-tenant sequential engines.
+"""Multi-tenant adapter serving benchmark: one mixed-tenant engine vs
+per-tenant sequential engines, on a reduced GPT2-S.
 
-Two phases on the reduced GPT2-S the other serving benches use:
-
-* gather dispatch overhead — ``lora_matmul_gathered`` over an 8-adapter
-  pool vs the single-adapter ``lora_matmul`` on the same (M, K, N, r)
-  problem, both through the CPU dispatch path the engine runs here (the
-  Pallas twins are interpret-mode-only in this container).  The per-row
-  adapter gather must stay a bounded tax over the single-adapter fused
-  matmul; ``check_regression.py`` gates the within-run ratio.
-
-* mixed batch vs sequential at EQUAL HBM — the same 12-request workload
-  over 6 distinct tenant adapters is served by (a) ONE multi-tenant
-  engine batching all tenants into every fused step, and (b) one
-  single-adapter engine PER TENANT run back to back, each sized to the
-  same KV page pool and base weights (only one sequential engine is live
-  at a time, so peak HBM matches).  Engine steps to drain are
-  deterministic counts — the us column carries STEPS (noise-free gate
-  ratio); wall-clock tokens/sec ride in the derived field.  Batching
-  distinct tenants is the whole point of the gather kernel: the
-  sequential baseline pays ~num_tenants more steps.
+Mixed batch vs sequential at EQUAL HBM — the same 12-request workload
+over 6 distinct tenant adapters is served by (a) ONE multi-tenant engine
+batching all tenants into every fused step, and (b) one single-adapter
+engine PER TENANT run back to back, each sized to the same KV page pool
+and base weights (only one sequential engine is live at a time, so peak
+HBM matches).  Engine steps to drain are deterministic counts — the us
+column carries STEPS (noise-free gate ratio).  Batching distinct tenants
+is the whole point of the gather kernel: the sequential baseline pays
+~num_tenants more steps.
 
 Rows land in ``BENCH_multitenant.json`` (``benchmarks.run`` snapshots
-``multitenant/``); ``check_regression.py`` gates the gather-overhead and
-mixed-vs-sequential ratios against the committed baseline.
+``multitenant/``); ``check_regression.py`` gates the mixed-vs-sequential
+ratio against the committed baseline.
 """
 from __future__ import annotations
 
-import time
-
 import jax
-import jax.numpy as jnp
 import numpy as np
 
-
-def _time(fn, *args, iters=5):
-    fn(*args).block_until_ready()
-    t0 = time.time()
-    for _ in range(iters):
-        out = fn(*args)
-    out.block_until_ready()
-    return (time.time() - t0) / iters * 1e6
-
-
-# ---------------------------------------------------------------------------
-# phase 1: batched-gather dispatch overhead vs single-adapter
-# ---------------------------------------------------------------------------
-
-def _gather_overhead(emit):
-    from repro.kernels.lora_matmul import lora_matmul, lora_matmul_gathered
-
-    M, K, N, r, A = 256, 1024, 1024, 8, 8
-    ks = jax.random.split(jax.random.key(0), 5)
-    x = jax.random.normal(ks[0], (M, K))
-    w = jax.random.normal(ks[1], (K, N)) * K ** -0.5
-    a1 = jax.random.normal(ks[2], (r, K)) * K ** -0.5
-    b1 = jax.random.normal(ks[3], (N, r))
-    # pool: adapter 0 == the single adapter, 7 more tenants stacked on top
-    ap = jnp.concatenate([a1[None],
-                          jax.random.normal(ks[4], (A - 1, r, K)) * K ** -0.5])
-    bp = jnp.concatenate([b1[None],
-                          jax.random.normal(jax.random.key(9), (A - 1, N, r))])
-    idx = jnp.arange(M, dtype=jnp.int32) % A       # every adapter in use
-
-    single = jax.jit(lambda *z: lora_matmul(*z, scale=1.0))
-    gather = jax.jit(lambda *z: lora_matmul_gathered(*z, scale=1.0))
-    ts = _time(single, x, w, a1, b1)
-    tg = _time(gather, x, w, ap, bp, idx)
-    emit("multitenant/lora_single_cpu", ts, f"M={M};K={K};N={N};r={r}")
-    emit("multitenant/lora_gather_cpu", tg,
-         f"pool={A};distinct_adapters_in_batch={A};"
-         f"overhead_vs_single={tg / max(ts, 1e-9) - 1.0:+.1%}")
-
-
-# ---------------------------------------------------------------------------
-# phase 2: one mixed-tenant engine vs per-tenant sequential engines
-# ---------------------------------------------------------------------------
 
 def _workload(cfg, num_tenants, per_tenant, seed=4):
     """(tenant, prompt, max_new) rows — deterministic, round-robin."""
@@ -90,15 +34,14 @@ def _workload(cfg, num_tenants, per_tenant, seed=4):
 def _drain(eng, reqs, max_steps=5_000):
     for r in reqs:
         eng.submit(r)
-    t0, steps = time.time(), 0
+    steps = 0
     while steps < max_steps:
         if not eng.queue and all(s is None for s in eng.slots):
             break
         eng.step()
         steps += 1
-    wall = time.time() - t0
     assert all(r.done for r in reqs), "workload did not drain"
-    return steps, sum(len(r.output) for r in reqs), wall
+    return steps, sum(len(r.output) for r in reqs)
 
 
 def _mixed_vs_sequential(emit):
@@ -124,14 +67,13 @@ def _mixed_vs_sequential(emit):
                         sc=SampleConfig(greedy=True))
     mixed_reqs = [Request(uid=i, prompt=p, max_new_tokens=g, tenant=t)
                   for i, (t, p, g) in enumerate(work)]
-    steps_mixed, toks_mixed, wall_mixed = _drain(eng, mixed_reqs)
+    steps_mixed, toks_mixed = _drain(eng, mixed_reqs)
     assert eng._jit_step_paged._cache_size() == 1
 
     # (b) one single-adapter engine per tenant, run back to back; each
     # engine has the SAME page pool / base params, and only one is live
     # at a time -> equal peak HBM
     steps_seq = toks_seq = 0
-    wall_seq = 0.0
     seq_out = {}
     for t in range(NT):
         e1 = ServingEngine(cfg, params, lora=adapters[t], max_slots=SLOTS,
@@ -139,10 +81,9 @@ def _mixed_vs_sequential(emit):
                            sc=SampleConfig(greedy=True))
         reqs = [Request(uid=i, prompt=p, max_new_tokens=g)
                 for i, (tt, p, g) in enumerate(work) if tt == t]
-        s, k, w_ = _drain(e1, reqs)
+        s, k = _drain(e1, reqs)
         steps_seq += s
         toks_seq += k
-        wall_seq += w_
         for r in reqs:
             seq_out[r.uid] = r.output
     # same workload, same tokens — and token-identical per request
@@ -152,20 +93,16 @@ def _mixed_vs_sequential(emit):
     # STEPS in the us column: deterministic, gate-stable
     emit("multitenant/steps_mixed", steps_mixed,
          f"unit=steps;tenants={NT};slots={SLOTS};tokens={toks_mixed};"
-         f"tok_s={toks_mixed / max(wall_mixed, 1e-9):.1f};"
          f"adapter_swaps={eng.stats['adapter_swaps']}")
     emit("multitenant/steps_sequential", steps_seq,
          f"unit=steps;engines={NT};tokens={toks_seq};"
-         f"tok_s={toks_seq / max(wall_seq, 1e-9):.1f};"
-         f"mixed_speedup={steps_seq / max(steps_mixed, 1):.2f}x_steps_"
-         f"{wall_seq / max(wall_mixed, 1e-9):.2f}x_wall")
+         f"mixed_speedup={steps_seq / max(steps_mixed, 1):.2f}x_steps")
     tt = eng.stats["tenant_tokens"]
     emit("multitenant/tokens_delivered", toks_mixed,
          f"unit=tokens;per_tenant={ {t: tt[t] for t in sorted(tt)} }")
 
 
 def main(emit):
-    _gather_overhead(emit)
     _mixed_vs_sequential(emit)
 
 

@@ -1,6 +1,6 @@
 """Precision benchmarks: what quantizing the split boundary buys and costs.
 
-Three deterministic row groups land in ``BENCH_precision.json``:
+Two deterministic row groups land in ``BENCH_precision.json``:
 
   delay     the allocator's bits axis — per-client BCD on the edge
             scenario with ``bits_candidates=(16,)`` (the pre-precision
@@ -17,10 +17,6 @@ Three deterministic row groups land in ``BENCH_precision.json``:
             in milli-units; the quantized run must land within 2% of
             f32 (asserted — the paper-level claim that an int8 boundary
             is convergence-neutral), and the ratio is gated.
-
-  kernel    micro wall-times of the fused LoRA matmul with f32 vs
-            weight-only int8 base (informational, not gated: raw times
-            do not transfer between machines).
 """
 from __future__ import annotations
 
@@ -104,8 +100,7 @@ def _episode(prob, alloc, params, batch, ev_batch, *, precision):
 
 def main(emit) -> None:
     from repro.core import bcd_minimize_delay_per_client
-    from repro.kernels.lora_matmul import lora_matmul
-    from repro.precision import PrecisionConfig, quantize_weight_int8
+    from repro.precision import PrecisionConfig
 
     # ---- allocator: the bits axis vs the pre-precision search -------------
     (a16, h16), t16 = _timed(
@@ -140,25 +135,6 @@ def main(emit) -> None:
     emit("precision/loss_quant_milli", 1e3 * q_loss,
          f"unit=milli_loss;vs_f32={q_loss / max(f32_loss, 1e-9):.3f}x;"
          f"act=8;grad=8;sr=1;ef=1;wall_s={w_q:.1f}")
-
-    # ---- kernel micro: weight-only int8 base in the fused matmul ----------
-    M_, K_, N, r = 256, 768, 768, 8
-    x = jax.random.normal(jax.random.key(0), (M_, K_))
-    w = jax.random.normal(jax.random.key(1), (K_, N)) * K_ ** -0.5
-    a = jax.random.normal(jax.random.key(2), (r, K_)) * K_ ** -0.5
-    b = jax.random.normal(jax.random.key(3), (N, r))
-    wq, ws = quantize_weight_int8(w)
-
-    f32_fn = jax.jit(lambda x: lora_matmul(x, w, a, b))
-    q8_fn = jax.jit(lambda x: lora_matmul(x, wq, a, b, w_scale=ws))
-    f32_fn(x).block_until_ready()
-    q8_fn(x).block_until_ready()
-    _, t_f32 = _timed(lambda: f32_fn(x).block_until_ready(), repeats=10)
-    _, t_q8 = _timed(lambda: q8_fn(x).block_until_ready(), repeats=10)
-    err = float(jnp.abs(q8_fn(x) - f32_fn(x)).max())
-    emit("precision/lora_f32_cpu", t_f32 * 1e6, f"{M_}x{K_}x{N},r={r}")
-    emit("precision/lora_q8_cpu", t_q8 * 1e6,
-         f"overhead={t_q8 / max(t_f32, 1e-9):.2f}x;max_err={err:.3f}")
 
 
 if __name__ == "__main__":

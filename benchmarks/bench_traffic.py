@@ -1,31 +1,20 @@
 """Traffic-trace serving benchmark: paged vs slab KV under load.
 
-Two phases, both on the same reduced GPT2-S the other serving benches use:
-
-* steady-state decode at EQUAL OCCUPANCY — both engines run the same 4
-  fully-admitted slots, so the row pair isolates the per-step cost of
-  paging (block-table gather + in-graph alloc/free) against the slab's
-  contiguous cache.  Acceptance: paged within a few percent of slab
-  (``check_regression.py`` gates the ratio).
-
-* Poisson load at EQUAL KV HBM — the same arrival trace (Poisson
-  arrivals in engine-step time, lognormal prompt/output lengths) is
-  served by a slab engine with ``slots * max_len`` worst-case tokens and
-  a paged engine whose page pool holds the SAME total tokens but is
-  shared by 3x the slots.  The paged engine admits work that the slab
-  queues behind head-of-line worst-case reservations, so time-to-first-
-  token collapses.  TTFT rows are recorded in deterministic STEP units
-  (the us column holds steps — the trace and admission are fully
-  deterministic, so the regression-gate ratio is noise-free); derived
-  fields carry the wall-scaled values.
+Poisson load at EQUAL KV HBM on a reduced GPT2-S: the same arrival trace
+(Poisson arrivals in engine-step time, lognormal prompt/output lengths)
+is served by a slab engine with ``slots * max_len`` worst-case tokens and
+a paged engine whose page pool holds the SAME total tokens but is shared
+by 3x the slots.  The paged engine admits work that the slab queues
+behind head-of-line worst-case reservations, so time-to-first-token
+collapses.  TTFT rows are recorded in deterministic STEP units (the us
+column holds steps — the trace and admission are fully deterministic, so
+the regression-gate ratio is noise-free).
 
 Rows land in ``BENCH_traffic.json`` (``benchmarks.run`` snapshots
-``traffic/``); ``check_regression.py`` gates paged-vs-slab steady decode
-and p99-TTFT ratios against the committed baseline.
+``traffic/``); ``check_regression.py`` gates the p99-TTFT ratio against
+the committed baseline.
 """
 from __future__ import annotations
-
-import time
 
 import jax
 import numpy as np
@@ -56,34 +45,6 @@ def _kv_bytes_per_token(cfg) -> int:
     return n_attn * 2 * cfg.num_kv_heads * cfg.head_dim * 4
 
 
-# ---------------------------------------------------------------------------
-# phase 1: steady-state decode at equal occupancy
-# ---------------------------------------------------------------------------
-
-def _steady_state(cfg, params, *, paged, steps=30):
-    from repro.serving import Request
-
-    slots, max_len = 4, 128
-    eng = _engine(cfg, params, paged=paged, slots=slots, max_len=max_len)
-    rng = np.random.default_rng(0)
-    for i in range(slots):
-        eng.submit(Request(uid=i,
-                           prompt=rng.integers(5, cfg.vocab_size, 24).tolist(),
-                           max_new_tokens=steps + 16))
-    eng.step()                      # admit all + compile
-    eng.step()                      # warm
-    t0 = time.time()
-    decoded = 0
-    for _ in range(steps):
-        decoded += eng.step()
-    wall = time.time() - t0
-    return wall / steps * 1e6, decoded / wall
-
-
-# ---------------------------------------------------------------------------
-# phase 2: Poisson traffic at equal KV HBM
-# ---------------------------------------------------------------------------
-
 def _trace(cfg, n=60, lam=1.5, seed=3):
     """(arrival_step, prompt, max_new) per request — Poisson arrivals,
     lognormal lengths, deterministic."""
@@ -99,13 +60,12 @@ def _trace(cfg, n=60, lam=1.5, seed=3):
 
 
 def _run_load(eng, trace, max_steps=5_000):
-    """Serve the trace; returns (ttft_steps per request, mean live slots,
-    total tokens, wall seconds, total steps)."""
+    """Serve the trace; returns (ttft_steps per request, mean live
+    slots)."""
     from repro.serving import Request
 
     reqs, arrived_at, first_tok = {}, {}, {}
     idx, step, live_sum = 0, 0, 0
-    t0 = time.time()
     while step < max_steps:
         while idx < len(trace) and trace[idx][0] <= step:
             at, prompt, gen = trace[idx]
@@ -122,27 +82,15 @@ def _run_load(eng, trace, max_steps=5_000):
                 first_tok[uid] = step
         live_sum += sum(s is not None for s in eng.slots)
         step += 1
-    wall = time.time() - t0
     assert all(r.done for r in reqs.values()), "trace did not drain"
     ttft = [first_tok[u] - arrived_at[u] + 1 for u in reqs]
-    total = sum(len(r.output) for r in reqs.values())
-    return ttft, live_sum / max(step, 1), total, wall, step
+    return ttft, live_sum / max(step, 1)
 
 
 def main(emit):
     cfg, params = _setup()
     per_tok = _kv_bytes_per_token(cfg)
 
-    # -- phase 1: equal occupancy, per-step decode cost ------------------
-    us_paged, tok_s_paged = _steady_state(cfg, params, paged=True)
-    us_slab, tok_s_slab = _steady_state(cfg, params, paged=False)
-    emit("traffic/decode_paged", us_paged,
-         f"tok_s={tok_s_paged:.1f};slots=4;max_len=128")
-    emit("traffic/decode_slab", us_slab,
-         f"tok_s={tok_s_slab:.1f};paged_overhead="
-         f"{us_paged / max(us_slab, 1e-9) - 1.0:+.1%}")
-
-    # -- phase 2: equal KV HBM, Poisson load -----------------------------
     # slab: 4 slots x 192 tokens = 768 worst-case tokens.
     # paged: a 48-page x 16-token pool = the SAME 768 tokens of HBM
     # (null page included), shared by 12 slots — 3x the admission width.
@@ -159,19 +107,13 @@ def main(emit):
         ("paged", _engine(cfg, params, paged=True, slots=12,
                           max_len=max_len, num_pages=pages, page_size=PS)),
     ):
-        ttft, conc, total, wall, steps = _run_load(eng, trace)
-        us_step = wall / max(steps, 1) * 1e6
-        p50 = float(np.percentile(ttft, 50))
-        p99 = float(np.percentile(ttft, 99))
+        ttft, conc = _run_load(eng, trace)
         results[name] = conc
-        # TTFT rows carry deterministic STEPS in the us column (gate-
-        # stable); wall-scaled values ride in derived
-        emit(f"traffic/ttft_p50_{name}", p50,
-             f"unit=steps;us={p50 * us_step:.0f}")
-        emit(f"traffic/ttft_p99_{name}", p99,
-             f"unit=steps;us={p99 * us_step:.0f}")
-        emit(f"traffic/tok_s_{name}", us_step,
-             f"tok_s={total / wall:.1f};steps={steps};tokens={total}")
+        # TTFT rows carry deterministic STEPS in the us column
+        emit(f"traffic/ttft_p50_{name}", float(np.percentile(ttft, 50)),
+             "unit=steps")
+        emit(f"traffic/ttft_p99_{name}", float(np.percentile(ttft, 99)),
+             "unit=steps")
         emit(f"traffic/concurrency_{name}", conc,
              f"unit=mean_live_slots;requests={len(trace)}")
         emit(f"traffic/peak_kv_bytes_{name}",

@@ -5,16 +5,18 @@
   table4  bench_ppl          centralized vs SflLLM perplexity
   fig3/4  bench_convergence  loss curves + steps-to-target per rank (+E(r) fit)
   fig5-8  bench_latency      latency sweeps, proposed vs baselines a-d
-  kernels bench_kernels      kernel twins micro-times + traffic accounting
-  serving bench_serving      fused vs naive engine tokens/sec + compiles
-  traffic bench_traffic      paged vs slab KV: steady decode + Poisson TTFT
+  traffic bench_traffic      paged vs slab KV: Poisson TTFT in engine steps
   roofline bench_roofline    per (arch x shape x mesh) roofline rows
   resource bench_resource    BCD wall time + homogeneous-vs-hetero delay
-  dynamic bench_dynamic      dynamic-round overhead + adaptive re-allocation
+  dynamic bench_dynamic      adaptive re-allocation over a fading episode
   faults  bench_faults       failure-recovery cost: preemption recompute + rollback
   byzantine bench_byzantine  attacker damage vs robust-aggregation defense
-  multitenant bench_multitenant  batched-gather LoRA + mixed-tenant vs sequential
+  multitenant bench_multitenant  mixed-tenant engine vs sequential engines
   precision bench_precision  bits-axis delay gain + int8-boundary episode loss
+
+Rows are the paper's tables and figures, in model seconds, step counts,
+losses and allocator wall time.  Device speed is not measured here: it is
+measured on the chip by ``bench/``.
 
 Usage: PYTHONPATH=src python -m benchmarks.run [--only table4,fig5 ...]
 """
@@ -27,17 +29,15 @@ import time
 import traceback
 
 from . import (bench_byzantine, bench_complexity, bench_convergence,
-               bench_dynamic, bench_faults, bench_kernels, bench_latency,
-               bench_multitenant, bench_ppl, bench_precision, bench_resource,
-               bench_roofline, bench_serving, bench_traffic)
+               bench_dynamic, bench_faults, bench_latency, bench_multitenant,
+               bench_ppl, bench_precision, bench_resource, bench_roofline,
+               bench_traffic)
 
 SUITES = {
     "table3": bench_complexity.main,
     "table4": bench_ppl.main,
     "convergence": bench_convergence.main,
     "latency": bench_latency.main,
-    "kernels": bench_kernels.main,
-    "serving": bench_serving.main,
     "traffic": bench_traffic.main,
     "roofline": bench_roofline.main,
     "resource": bench_resource.main,
@@ -48,13 +48,10 @@ SUITES = {
     "precision": bench_precision.main,
 }
 
-# perf-trajectory snapshots: these row prefixes land in JSON files CI
-# archives per commit (and checks against benchmarks/baselines/ via
-# benchmarks/check_regression.py), so steady-state perf regressions are
-# diffable and gated from this PR onward
+# snapshots: these row prefixes land in JSON files CI archives per commit
+# (and checks against benchmarks/baselines/ via
+# benchmarks/check_regression.py)
 SNAPSHOTS = {
-    "BENCH_kernels.json": ("kernel/", "engine/"),
-    "BENCH_serving.json": ("serving/",),
     "BENCH_traffic.json": ("traffic/",),
     "BENCH_resource.json": ("resource/",),
     "BENCH_dynamic.json": ("dynamic/",),

@@ -1,18 +1,15 @@
 """Continuous-batching serving demo: stream E2E-style requests of varying
 length through a fixed-slot engine (deliverable b, serving scenario).
 
-The engine's fused path decodes every live slot, samples, and advances
-slot state in ONE jitted buffer-donated call per token; admission
-prefills into power-of-two length buckets so mixed prompt lengths stay
-within log2(max_len) compiles.  The run ends by replaying the same
-traffic through the pre-PR naive loop for a throughput comparison.
+The engine decodes every live slot, samples, and advances slot state in
+ONE jitted buffer-donated call per token; admission streams each prompt
+through one compiled chunked-prefill program, whatever its length.
 
     PYTHONPATH=src python examples/continuous_batching.py
 """
 import time
 
 import jax
-import numpy as np
 
 from repro.configs import get_arch
 from repro.data import WordTokenizer, e2e_splits
@@ -29,8 +26,6 @@ lora = M.init_lora_stack(cfg, key, rank=4)
 train, _, test = e2e_splits(500, 50, 50)
 tok = WordTokenizer.from_corpus([e.text for e in train])
 
-rng = np.random.default_rng(0)
-
 
 def make_requests():
     return [Request(uid=i, prompt=tok.encode(e.mr) + [SEP],
@@ -38,33 +33,23 @@ def make_requests():
             for i, e in enumerate(test[:10])]
 
 
-def serve(fused: bool):
-    eng = ServingEngine(cfg, params, lora=lora, max_slots=3, max_len=96,
-                        sc=SampleConfig(greedy=True), fused=fused)
-    requests = make_requests()
-    for r in requests:
-        eng.submit(r)
-    t0 = time.time()
-    steps = 0
-    while any(not r.done for r in requests):
-        n = eng.step()
-        steps += 1
-        if fused and steps % 5 == 0:
-            done = sum(r.done for r in requests)
-            print(f"step {steps:3d}: {n} live slots, "
-                  f"{done}/{len(requests)} done")
-    wall = time.time() - t0
-    total = sum(len(r.output) for r in requests)
-    return requests, total, wall, eng.prefill_compiles()
-
-
-requests, total, wall, compiles = serve(fused=True)
-print(f"\nfused engine: {len(requests)} requests / {total} tokens in "
-      f"{wall:.1f}s ({total/wall:.1f} tok/s), {compiles} prefill compiles")
+eng = ServingEngine(cfg, params, lora=lora, max_slots=3, max_len=96,
+                    sc=SampleConfig(greedy=True))
+requests = make_requests()
+for r in requests:
+    eng.submit(r)
+t0 = time.time()
+steps = 0
+while any(not r.done for r in requests):
+    n = eng.step()
+    steps += 1
+    if steps % 5 == 0:
+        done = sum(r.done for r in requests)
+        print(f"step {steps:3d}: {n} live slots, "
+              f"{done}/{len(requests)} done")
+wall = time.time() - t0
+total = sum(len(r.output) for r in requests)
+print(f"\nserved {len(requests)} requests / {total} tokens in {steps} "
+      f"engine steps ({wall:.1f}s), {eng.prefill_compiles()} prefill "
+      f"compiles")
 print("sample:", tok.decode(requests[0].output[:10]))
-
-req_naive, total_n, wall_n, _ = serve(fused=False)
-print(f"naive loop:   {total_n} tokens in {wall_n:.1f}s "
-      f"({total_n/wall_n:.1f} tok/s)")
-assert [r.output for r in requests] == [r.output for r in req_naive]
-print(f"outputs identical; fused speedup {wall_n / wall:.2f}x")

@@ -411,11 +411,6 @@ class SflLLM:
         # every jitted entry takes the frozen ``base`` first, the state second
         self._jit_local_step = jax.jit(self._local_step)
         self._jit_eval = jax.jit(self._eval_loss)
-        # legacy unmasked round — kept as the bench baseline for the
-        # masking overhead (benchmarks/bench_dynamic.py); train_round
-        # itself always runs the masked graph below
-        self._jit_round = jax.jit(self._train_round,
-                                  donate_argnums=(1,) if donate else ())
         self._jit_round_part = jax.jit(self._train_round_part,
                                        donate_argnums=(1,) if donate else ())
         self._jit_mask = jax.jit(self._dropout_mask,
@@ -872,18 +867,6 @@ class SflLLM:
                                jnp.asarray(list(sample_counts), jnp.float32))
 
     # ------------------------------------------------------------------
-    def _train_round(self, base, state: SflState, round_batches, weights):
-        """One compiled global round: lax.scan over the I local steps, then
-        in-graph FedAvg — a single XLA program per round instead of K*I
-        host dispatches.
-
-        round_batches: tokens (I, K, b, S), labels (I, K, b, S), optional
-        frontend_emb (I, K, b, F, d); weights: (K,) sample counts."""
-        self._round_traces += 1       # trace-time only: retrace telemetry
-        state, metrics = jax.lax.scan(
-            lambda st, b: self._local_step(base, st, b), state, round_batches)
-        return self._aggregate(state, weights), metrics
-
     def _train_round_part(self, base, state: SflState, round_batches,
                           weights, part, cfg_dyn, poison=None, robust=None,
                           byz=None):
@@ -894,6 +877,9 @@ class SflLLM:
         pass this round's values.  Same structure => ONE trace for the
         entire episode, and full participation is bit-identical to a static
         round because it IS the same executable.
+
+        round_batches: tokens (I, K, b, S), labels (I, K, b, S), optional
+        frontend_emb (I, K, b, F, d); weights: (K,) sample counts.
 
         Divergence rollback: after the scan + aggregation the whole new
         state is checked all-finite in-graph (``tree_all_finite``); a
@@ -1204,8 +1190,8 @@ class CentralizedLoRA:
             return jax.lax.scan(body, carry, round_batches)
 
         self._jit_step = jax.jit(step)
-        self._jit_round = jax.jit(round_,
-                                  donate_argnums=(0,) if donate else ())
+        self._jit_train_round = jax.jit(
+            round_, donate_argnums=(0,) if donate else ())
 
     def init_state(self, lora):
         # fresh buffers: train_round donates state, which must never delete
@@ -1222,4 +1208,4 @@ class CentralizedLoRA:
         input buffers are donated."""
         batches = {k: jnp.asarray(v) for k, v in round_batches.items()
                    if v is not None}
-        return self._jit_round(state, batches)
+        return self._jit_train_round(state, batches)

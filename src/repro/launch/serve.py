@@ -1,7 +1,7 @@
 """Serving driver: continuous-batching engine over the (optionally
 LoRA-adapted) model — fused in-graph decode with a paged KV cache and
-chunked prefill by default (``--slab`` forces the fixed-slab layout,
-``--naive`` the pre-PR host loop).  CPU demo:
+chunked prefill by default (``--slab`` forces the fixed-slab layout).
+CPU demo:
 
   PYTHONPATH=src python -m repro.launch.serve --arch gpt2-s --reduced \
       --requests 12 --slots 4 --gen 16
@@ -25,8 +25,6 @@ def main() -> None:
     ap.add_argument("--lora-checkpoint", default="")
     ap.add_argument("--temperature", type=float, default=0.0,
                     help="0 = greedy")
-    ap.add_argument("--naive", action="store_true",
-                    help="pre-PR per-token host loop (baseline)")
     ap.add_argument("--slab", action="store_true",
                     help="fixed-slab KV cache instead of the paged pool")
     ap.add_argument("--page-size", type=int, default=16)
@@ -95,18 +93,18 @@ def main() -> None:
 
     sc = (SampleConfig(greedy=True) if args.temperature == 0.0
           else SampleConfig(temperature=args.temperature))
-    paged = False if (args.slab or args.naive) else None    # None = auto
+    paged = False if args.slab else None    # None = auto
     eng = ServingEngine(cfg, params, lora=lora, adapters=registry,
                         tenant_quota=args.tenant_quota,
                         max_slots=args.slots,
                         max_len=args.max_len, sc=sc, seed=args.seed,
-                        fused=not args.naive, paged=paged,
+                        paged=paged,
                         page_size=args.page_size,
                         num_pages=args.num_pages or None,
                         preempt=args.preempt)
     if (args.deadline_steps or args.preempt) and not eng.paged:
         raise SystemExit("--deadline-steps/--preempt need the paged engine "
-                         "(drop --slab/--naive)")
+                         "(drop --slab)")
 
     rng = np.random.default_rng(args.seed)
 
@@ -135,9 +133,8 @@ def main() -> None:
         steps += 1
     wall = time.time() - t0
     total = sum(len(r.output) for r in reqs)
-    mode = "naive" if args.naive else ("slab" if not eng.paged else
-                                       f"paged(ps={eng.page_size},"
-                                       f"np={eng.num_pages})")
+    mode = ("slab" if not eng.paged else
+            f"paged(ps={eng.page_size},np={eng.num_pages})")
     print(f"served {len(reqs)} requests / {total} tokens in {wall:.2f}s "
           f"({total / wall:.1f} tok/s) with {args.slots} slots, "
           f"{steps} engine steps, {eng.prefill_compiles()} prefill "

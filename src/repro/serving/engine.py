@@ -7,8 +7,7 @@ of length ``max_len``; every cache leaf carries the slot axis at position
 writes, the batched decode, and admission are all plain indexed updates on
 one uniform pytree.
 
-The fused path (default) removes every per-token host round-trip the
-naive loop pays:
+Every per-token step stays on the device:
 
 * ``step()`` is ONE jitted, buffer-donated call: flash-decode all slots
   at their own positions (``models.decode_step`` with a (B,) position
@@ -22,15 +21,9 @@ naive loop pays:
 * admission prefills into a power-of-two length bucket (compile count is
   bounded by log2(max_len) for ANY prompt-length mix) and writes the
   bucket's KV into the slot with per-slot ``dynamic_update_slice`` inside
-  jit — not the full-cache host copy the naive path does;
+  jit;
 * a slot whose cache fills (position reaching ``max_len``) is finished
   and freed instead of silently wrapping the ring.
-
-``fused=False`` keeps the pre-PR execution shape (per-slot vmapped
-decode, host-side sampling, full-cache admission copy) as the measured
-baseline for the serving benchmark and the fused-vs-naive equivalence
-test; it shares the per-request RNG streams so both modes sample
-identically.
 
 PAGED KV (default where eligible): instead of per-slot ``max_len`` slabs
 the KV lives in a global page pool ``(KH, num_pages, page_size, D)`` with
@@ -56,12 +49,12 @@ measures the TTFT/throughput win under Poisson traffic):
   outputs stay independent of page layout, slot index, and arrival
   order — the paged engine is token-identical to the slab engine.
 
-``paged=False`` forces the PR-3 slab layout (the benchmark baseline);
-mamba/windowed/frontend archs fall back to it automatically.
+``paged=False`` forces the slab layout; mamba/windowed/frontend archs
+fall back to it automatically.
 
-FAILURE HANDLING (paged engine only — the slab/naive paths stay frozen
-baselines): the fused step additionally takes per-slot eviction flags, a
-per-slot residency deadline, and a NaN-injection mask, all traced data —
+FAILURE HANDLING (paged engine only): the fused step additionally takes
+per-slot eviction flags, a per-slot residency deadline, and a
+NaN-injection mask, all traced data —
 
 * preemptive KV eviction: under page pressure (``preempt=True``) the host
   flags a strictly-lower-priority victim; a victim (or a slot whose
@@ -188,7 +181,7 @@ class ServingEngine:
     def __init__(self, cfg, params, *, lora=None, rt: Optional[Runtime] = None,
                  max_slots: int = 4, max_len: int = 256,
                  sc: SampleConfig = SampleConfig(greedy=True), seed: int = 0,
-                 fused: bool = True, prefill_buckets: bool = True,
+                 prefill_buckets: bool = True,
                  paged: Optional[bool] = None, page_size: int = 16,
                  num_pages: Optional[int] = None, preempt: bool = False,
                  adapters=None, tenant_quota: int = 0):
@@ -199,7 +192,6 @@ class ServingEngine:
         self.cfg, self.params, self.lora = cfg, params, lora
         self.rt = rt if rt is not None else default_serve_runtime()
         self.max_slots, self.max_len, self.sc = max_slots, max_len, sc
-        self.fused = fused
         # right-padded bucket prefill assumes pad entries can be masked out
         # of an attention cache tail; recurrent (mamba) state and windowed
         # rings have no such tail — those archs prefill at exact length
@@ -207,13 +199,10 @@ class ServingEngine:
                                 all(p.mixer == "attention" for p in cfg.pattern))
         # paged KV needs the same length-contiguous attention-only shape,
         # and the chunk == page layout needs max_len to divide into pages
-        paged_ok = (fused and not cfg.attn_window and
+        paged_ok = (not cfg.attn_window and
                     all(p.mixer == "attention" for p in cfg.pattern))
         if paged is None:
             paged = paged_ok and max_len % page_size == 0
-        elif paged and not fused:
-            raise ValueError("paged KV requires the fused engine "
-                             "(page alloc/free live inside the fused step)")
         elif paged and not paged_ok:
             raise NotImplementedError(
                 "paged KV requires an attention-only, non-windowed pattern")
@@ -229,7 +218,7 @@ class ServingEngine:
             if not self.paged:
                 raise NotImplementedError(
                     "multi-tenant adapters require the paged engine "
-                    "(fused, attention-only, max_len % page_size == 0)")
+                    "(attention-only, max_len % page_size == 0)")
             if adapters.pool_size < max_slots:
                 # with pool >= slots an admission can always pin the <=
                 # max_slots-1 live tenants and still find a victim slot
@@ -243,7 +232,7 @@ class ServingEngine:
         self.queue: collections.deque[Request] = collections.deque()
         self.slots: List[Optional[Request]] = [None] * max_slots
 
-        # per-slot device state (fused path reads/writes these in-graph)
+        # per-slot device state (the fused step reads/writes these in-graph)
         B = max_slots
         self._last = jnp.zeros((B,), jnp.int32)
         self._positions = jnp.zeros((B,), jnp.int32)   # next write index
@@ -252,9 +241,6 @@ class ServingEngine:
         self._ngen = jnp.zeros((B,), jnp.int32)
         self._maxnew = jnp.zeros((B,), jnp.int32)
         self._eos = jnp.full((B,), -1, jnp.int32)
-        # host-side mirrors for the legacy (fused=False) loop
-        self._np_positions = np.zeros(B, np.int64)
-        self._np_last = np.zeros(B, np.int64)
         # failure handling (paged only): per-slot decode-step age vs the
         # request's residency deadline, host-set eviction / NaN-injection
         # flags (cleared every step), and recovery counters
@@ -468,15 +454,6 @@ class ServingEngine:
 
         self._jit_prefill = jax.jit(_prefill)
 
-        # -- legacy full-cache prefill (naive admission path) ------------
-        def _prefill_full(params, lora, tokens, uid):
-            logits, cache1 = model_mod.prefill(cfg, params, tokens, lora=lora,
-                                               rt=rt, cache_len=max_len)
-            k = jax.random.fold_in(jax.random.fold_in(base_key, uid), 0)
-            return sample_logits(logits, k, sc)[0], cache1
-
-        self._jit_prefill_full = jax.jit(_prefill_full)
-
         # -- in-graph slot admission: per-slot dynamic_update_slice ------
         def _admit_write(caches, last, positions, live, uids, ngen, maxnew,
                          eos, cache1, slot, tok0, true_len, uid, req_maxnew,
@@ -502,20 +479,6 @@ class ServingEngine:
         self._jit_admit = jax.jit(_admit_write,
                                   donate_argnums=(0, 1, 2, 3, 4, 5, 6, 7))
 
-        # -- legacy decode: per-slot vmap, logits back to host -----------
-        def _decode(params, lora, toks, caches, positions):
-            def one(tok, cache_slot, pos):
-                cache_b = jax.tree.map(lambda v: v[:, None], cache_slot)
-                logits, new_cache = model_mod.decode_step(
-                    cfg, params, tok[None, None], cache_b, pos,
-                    lora=lora, rt=rt)
-                return logits[0], jax.tree.map(lambda v: v[:, 0], new_cache)
-
-            return jax.vmap(one, in_axes=(0, 1, 0),
-                            out_axes=(0, 1))(toks, caches, positions)
-
-        self._jit_decode = jax.jit(_decode)
-
     # ------------------------------------------------------------------
     def submit(self, req: Request) -> None:
         if len(req.prompt) == 0:
@@ -534,8 +497,7 @@ class ServingEngine:
         bounded by the power-of-two bucket count)."""
         if self.paged:
             return self._jit_chunk._cache_size()
-        fn = self._jit_prefill if self.fused else self._jit_prefill_full
-        return fn._cache_size()
+        return self._jit_prefill._cache_size()
 
     def pages_in_use(self) -> int:
         """Pages currently allocated out of the in-graph pool."""
@@ -660,39 +622,26 @@ class ServingEngine:
             return False
         if self.paged:
             return self._admit_one_paged(s, req)
-        if self.fused:
-            # prompts longer than the largest power-of-two bucket (only
-            # possible for non-power-of-two max_len) prefill at exact
-            # length — bucket_len would otherwise return a bucket < P
-            cap = 1 << (self.max_len.bit_length() - 1)
-            use_bucket = self.prefill_buckets and P <= cap
-            Lb = bucket_len(P, self.max_len) if use_bucket else P
-            tokens = jnp.asarray(req.prompt + [0] * (Lb - P), jnp.int32)[None]
-            tok0_d, cache1 = self._jit_prefill(self.params, self.lora, tokens,
-                                               jnp.int32(P), jnp.int32(req.uid))
-        else:
-            tokens = jnp.asarray(req.prompt, jnp.int32)[None]
-            tok0_d, cache1 = self._jit_prefill_full(self.params, self.lora,
-                                                    tokens, jnp.int32(req.uid))
+        # prompts longer than the largest power-of-two bucket (only
+        # possible for non-power-of-two max_len) prefill at exact length —
+        # bucket_len would otherwise return a bucket < P
+        cap = 1 << (self.max_len.bit_length() - 1)
+        use_bucket = self.prefill_buckets and P <= cap
+        Lb = bucket_len(P, self.max_len) if use_bucket else P
+        tokens = jnp.asarray(req.prompt + [0] * (Lb - P), jnp.int32)[None]
+        tok0_d, cache1 = self._jit_prefill(self.params, self.lora, tokens,
+                                           jnp.int32(P), jnp.int32(req.uid))
         tok0 = int(tok0_d)
         req.output.append(tok0)
         if (tok0 == req.eos_id) or (req.max_new_tokens <= 1):
             req.done = True
             return False
-        if self.fused:
-            (self.caches, self._last, self._positions, self._live, self._uids,
-             self._ngen, self._maxnew, self._eos) = self._jit_admit(
-                self.caches, self._last, self._positions, self._live,
-                self._uids, self._ngen, self._maxnew, self._eos, cache1,
-                jnp.int32(s), tok0_d, jnp.int32(P), jnp.int32(req.uid),
-                jnp.int32(req.max_new_tokens), jnp.int32(req.eos_id))
-        else:
-            # pre-PR execution shape: copy the WHOLE cache tree per admit
-            self.caches = jax.tree.map(
-                lambda big, one: big.at[:, s].set(one[:, 0]),
-                self.caches, cache1)
-            self._np_positions[s] = P
-            self._np_last[s] = tok0
+        (self.caches, self._last, self._positions, self._live, self._uids,
+         self._ngen, self._maxnew, self._eos) = self._jit_admit(
+            self.caches, self._last, self._positions, self._live,
+            self._uids, self._ngen, self._maxnew, self._eos, cache1,
+            jnp.int32(s), tok0_d, jnp.int32(P), jnp.int32(req.uid),
+            jnp.int32(req.max_new_tokens), jnp.int32(req.eos_id))
         self.slots[s] = req
         return True
 
@@ -828,7 +777,7 @@ class ServingEngine:
                     self._reserved[s] = 0
             for req in reversed(front):     # oldest work back to the front
                 self.queue.appendleft(req)
-        elif self.fused:
+        else:
             (nxt, done, self.caches, self._last, self._positions, self._live,
              self._ngen) = self._jit_step(
                 self.params, self.lora, self.caches, self._last,
@@ -839,29 +788,6 @@ class ServingEngine:
                 req = self.slots[s]
                 req.output.append(int(nxt_h[s]))
                 if done_h[s]:
-                    req.done = True
-                    self.slots[s] = None
-        else:
-            toks = jnp.asarray(self._np_last, jnp.int32)
-            pos = jnp.asarray(self._np_positions, jnp.int32)
-            logits, self.caches = self._jit_decode(self.params, self.lora,
-                                                   toks, self.caches, pos)
-            uids = jnp.asarray([r.uid if r is not None else -1
-                                for r in self.slots], jnp.int32)
-            ngen = jnp.asarray([len(r.output) if r is not None else 0
-                                for r in self.slots], jnp.int32)
-            keys = jax.vmap(lambda u, n: jax.random.fold_in(
-                jax.random.fold_in(self.key, u), n))(uids, ngen)
-            nxt = np.asarray(sample_logits_per_key(logits, keys, self.sc))
-            for s in live:
-                req = self.slots[s]
-                tok = int(nxt[s])
-                req.output.append(tok)
-                self._np_positions[s] += 1
-                self._np_last[s] = tok
-                if (tok == req.eos_id) or \
-                        (len(req.output) >= req.max_new_tokens) or \
-                        (self._np_positions[s] >= self.max_len):
                     req.done = True
                     self.slots[s] = None
         return len(live)

@@ -1,7 +1,6 @@
 """Continuous-batching engine: outputs must equal independent greedy
-generation per request, under mixed admission order and slot reuse; the
-fused in-graph step must match the naive per-token loop; outputs must be
-a pure function of the request (arrival order / occupancy independent);
+generation per request, under mixed admission order and slot reuse;
+outputs must be a pure function of the request (arrival order / occupancy independent);
 prefill compiles must stay within the power-of-two bucket bound;
 ``bucket_len`` must stay a power of two (and >= the prompt) for
 non-power-of-two ``max_len``.  The default engine here is the PAGED one
@@ -67,7 +66,7 @@ def test_engine_eos_frees_slot(key):
 
 
 # ---------------------------------------------------------------------------
-# fused in-graph engine vs the naive per-token loop
+# outputs are a pure function of the request
 # ---------------------------------------------------------------------------
 
 def _shared_setup():
@@ -79,10 +78,9 @@ def _shared_setup():
     return cfg, params, prompts
 
 
-def _serve(cfg, params, prompts, order, *, fused, sc, seed=7):
+def _serve(cfg, params, prompts, order, *, sc, seed=7):
     eng = ServingEngine(cfg, params, rt=M.Runtime(attn_impl="naive"),
-                        max_slots=2, max_len=32, sc=sc, seed=seed,
-                        fused=fused)
+                        max_slots=2, max_len=32, sc=sc, seed=seed)
     reqs = {i: Request(uid=i, prompt=prompts[i], max_new_tokens=3 + i % 4)
             for i in order}
     for i in order:
@@ -95,27 +93,13 @@ def _serve(cfg, params, prompts, order, *, fused, sc, seed=7):
 @pytest.mark.parametrize("sc", [SampleConfig(greedy=True),
                                 SampleConfig(temperature=0.7)],
                          ids=["greedy", "temperature"])
-def test_fused_engine_matches_naive_loop(sc):
-    """The one-call fused step (in-graph sampling, donated buffers,
-    dynamic_update_slice admission) must produce token-identical outputs
-    to the pre-PR host loop on the same traffic."""
-    cfg, params, prompts = _shared_setup()
-    order = list(range(len(prompts)))
-    fused = _serve(cfg, params, prompts, order, fused=True, sc=sc)
-    naive = _serve(cfg, params, prompts, order, fused=False, sc=sc)
-    assert fused == naive
-
-
-@pytest.mark.parametrize("sc", [SampleConfig(greedy=True),
-                                SampleConfig(temperature=0.7)],
-                         ids=["greedy", "temperature"])
 def test_outputs_independent_of_arrival_order(sc):
     """Regression for the seed engine's RNG draw-for-dead-slots bug: the
     same requests submitted in a different order (hence different slot
     occupancy patterns) must produce identical per-request outputs."""
     cfg, params, prompts = _shared_setup()
-    a = _serve(cfg, params, prompts, [0, 1, 2, 3, 4, 5], fused=True, sc=sc)
-    b = _serve(cfg, params, prompts, [5, 2, 0, 4, 1, 3], fused=True, sc=sc)
+    a = _serve(cfg, params, prompts, [0, 1, 2, 3, 4, 5], sc=sc)
+    b = _serve(cfg, params, prompts, [5, 2, 0, 4, 1, 3], sc=sc)
     assert a == b
 
 
@@ -142,9 +126,9 @@ def test_bucketed_prefill_matches_exact_prefill():
     cfg, params, prompts = _shared_setup()
     sc = SampleConfig(greedy=True)
     rt = M.Runtime(attn_impl="naive")
-    for fused, buckets in ((True, True), (True, False)):
+    for buckets in (True, False):
         eng = ServingEngine(cfg, params, rt=rt, max_slots=1, max_len=32,
-                            sc=sc, fused=fused, prefill_buckets=buckets)
+                            sc=sc, prefill_buckets=buckets)
         req = Request(uid=0, prompt=prompts[0], max_new_tokens=5)
         eng.submit(req)
         eng.run()
